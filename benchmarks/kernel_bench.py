@@ -12,7 +12,7 @@ fused pair is the memory column: the einsum train path materialises the
 rebuild), while the fused forward and the recompute backward keep every
 per-``j`` intermediate in a (TB, H, TCO) VMEM tile.
 
-On this CPU-only container the fused kernels run in Pallas *interpret* mode
+On a CPU backend the fused kernels run in Pallas *interpret* mode
 (per-grid-instance Python), so walltime favours XLA's compiled einsum — the
 ``interpret_mode`` flag is recorded so downstream trajectory tooling doesn't
 read CPU walltime as the TPU story.

@@ -24,6 +24,8 @@ def main() -> None:
                          "others ignore it)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig5_pid, kernel_bench, pareto_bench, serve_bench,
                             table1_train_time, table2_jsc_hlf, table3_plf_tgc,
                             train_bench)

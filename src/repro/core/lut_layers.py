@@ -156,12 +156,18 @@ class LUTDense:
         return self._fused_forward(params, x, train=False)
 
     # ---------------------------------------------------------------- apply
+    def runs_fused(self, *, train: bool) -> bool:
+        """Whether :meth:`apply` takes the fused Pallas path.
+
+        BN+train needs batch-wide statistics -> einsum fallback; any other
+        structurally unsupported config raises inside ``_fused_forward``.
+        """
+        return self.use_fused and not (self.use_batchnorm and train)
+
     def apply(self, params: dict, x: Array, *, train: bool = False) -> Tuple[Array, Aux]:
         if x.shape[-1] != self.c_in:
             raise ValueError(f"expected (..., {self.c_in}), got {x.shape}")
-        # BN+train needs batch-wide statistics -> einsum fallback; any other
-        # structurally unsupported config raises inside _fused_forward.
-        if self.use_fused and not (self.use_batchnorm and train):
+        if self.runs_fused(train=train):
             out = self._fused_forward(params, x, train=train)
             eb = ebops_mod.ebops_lut(bitwidth(params["q_in"], self.q_in),
                                      bitwidth(params["q_out"], self.q_out))

@@ -57,29 +57,33 @@ def _fq_sat(x, f, i):
     return jnp.where(f + i + 1.0 > 0.0, q, 0.0)
 
 
+def _column(x, col, j):
+    """Column ``j`` of ``x`` as (TB, 1), for a traced ``j``.
+
+    Mosaic has no dynamic slice along the lane axis; a masked row sum picks
+    the column exactly (every other term is a zero).
+    """
+    return jnp.sum(jnp.where(col == j, x, 0.0), axis=1, keepdims=True)
+
+
 def _lut_dense_kernel(x_ref, w0_ref, b0_ref, wo_ref, bo_ref,
-                      fi_ref, ii_ref, fo_ref, io_ref, out_ref, *, c_in: int):
+                      fi_ref, ii_ref, fo_ref, io_ref, out_ref):
     """One (TB, TCO) output tile; fori over the C_in reduction axis."""
     x = x_ref[...].astype(jnp.float32)                      # (TB, C_in)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
 
     def body(j, acc):
-        xj = jax.lax.dynamic_slice_in_dim(x, j, 1, axis=1)   # (TB, 1)
-        fi = jax.lax.dynamic_slice_in_dim(fi_ref[...], j, 1, 0)  # (1, TCO)
-        ii = jax.lax.dynamic_slice_in_dim(ii_ref[...], j, 1, 0)
-        fo = jax.lax.dynamic_slice_in_dim(fo_ref[...], j, 1, 0)
-        io = jax.lax.dynamic_slice_in_dim(io_ref[...], j, 1, 0)
-        w0 = jax.lax.dynamic_slice_in_dim(w0_ref[...], j, 1, 0)[0]  # (H, TCO)
-        b0 = jax.lax.dynamic_slice_in_dim(b0_ref[...], j, 1, 0)[0]
-        wo = jax.lax.dynamic_slice_in_dim(wo_ref[...], j, 1, 0)[0]
-        bo = jax.lax.dynamic_slice_in_dim(bo_ref[...], j, 1, 0)     # (1, TCO)
-
-        xq = _fq_wrap(xj, fi, ii)                            # (TB, TCO)
-        h = jnp.tanh(xq[:, None, :] * w0[None] + b0[None])   # (TB, H, TCO)
-        y = jnp.sum(h * wo[None], axis=1) + bo               # (TB, TCO)
-        return acc + _fq_sat(y, fo, io)
+        row = lambda ref: ref[pl.ds(j, 1), :]                # (1, TCO)
+        slab = lambda ref: ref[pl.ds(j, 1)][0]               # (H, TCO)
+        xq = _fq_wrap(_column(x, col, j), row(fi_ref), row(ii_ref))
+        h = jnp.tanh(xq[:, None, :] * slab(w0_ref)[None]
+                     + slab(b0_ref)[None])                   # (TB, H, TCO)
+        y = jnp.sum(h * slab(wo_ref)[None], axis=1) + row(bo_ref)
+        return acc + _fq_sat(y, row(fo_ref), row(io_ref))
 
     acc = jnp.zeros(out_ref.shape, jnp.float32)
-    out_ref[...] = jax.lax.fori_loop(0, c_in, body, acc).astype(out_ref.dtype)
+    out_ref[...] = jax.lax.fori_loop(0, x.shape[1], body,
+                                     acc).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tb", "tco", "interpret"))
@@ -112,7 +116,7 @@ def lut_dense_fused(x, w0, b0, w_out, b_out, f_in, i_in, f_out, i_out,
     bspec_o = pl.BlockSpec((tb, tco), lambda ib, ic: (ib, ic))
 
     out = pl.pallas_call(
-        functools.partial(_lut_dense_kernel, c_in=c_in),
+        _lut_dense_kernel,
         grid=grid,
         in_specs=[bspec_x, bspec_w, bspec_w, bspec_w, bspec_q,
                   bspec_q, bspec_q, bspec_q, bspec_q],

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.lut_dense import DEF_TB, DEF_TCO
+from repro.kernels.lut_dense import DEF_TB, DEF_TCO, _column
 
 LOG2 = float(np.log(2.0))
 
@@ -45,7 +45,7 @@ LOG2 = float(np.log(2.0))
 def _lut_dense_bwd_kernel(x_ref, w0_ref, b0_ref, wo_ref, bo_ref,
                           fi_ref, ii_ref, fo_ref, io_ref, g_ref,
                           dx_ref, dw0_ref, db0_ref, dwo_ref, dbo_ref,
-                          dfi_ref, dfo_ref, dio_ref, *, c_in: int):
+                          dfi_ref, dfo_ref, dio_ref):
     """One (TB, TCO) cotangent tile; fori over C_in, recompute per slice."""
     ib = pl.program_id(1)
 
@@ -57,16 +57,16 @@ def _lut_dense_bwd_kernel(x_ref, w0_ref, b0_ref, wo_ref, bo_ref,
 
     x = x_ref[...].astype(jnp.float32)                       # (TB, C_in)
     g = g_ref[...].astype(jnp.float32)                       # (TB, TCO)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
 
     def body(j, acc_dx):
-        row2 = lambda ref: jax.lax.dynamic_slice_in_dim(ref[...], j, 1, 0)
-        xj = jax.lax.dynamic_slice_in_dim(x, j, 1, 1)        # (TB, 1)
-        fi, ii = row2(fi_ref), row2(ii_ref)                  # (1, TCO)
-        fo, io = row2(fo_ref), row2(io_ref)
-        bo = row2(bo_ref)
-        w0 = jax.lax.dynamic_slice_in_dim(w0_ref[...], j, 1, 0)[0]  # (H, TCO)
-        b0 = jax.lax.dynamic_slice_in_dim(b0_ref[...], j, 1, 0)[0]
-        wo = jax.lax.dynamic_slice_in_dim(wo_ref[...], j, 1, 0)[0]
+        row = lambda ref: ref[pl.ds(j, 1), :]                # (1, TCO)
+        slab = lambda ref: ref[pl.ds(j, 1)][0]               # (H, TCO)
+        xj = _column(x, col, j)                              # (TB, 1)
+        fi, ii = row(fi_ref), row(ii_ref)
+        fo, io = row(fo_ref), row(io_ref)
+        bo = row(bo_ref)
+        w0, b0, wo = slab(w0_ref), slab(b0_ref), slab(wo_ref)
 
         # ---- forward recompute (expressions identical to lut_dense.py) ----
         scale_i = jnp.exp2(-fi)
@@ -105,25 +105,16 @@ def _lut_dense_bwd_kernel(x_ref, w0_ref, b0_ref, wo_ref, bo_ref,
                         0, keepdims=True)
         gx_j = jnp.sum(jnp.where(alive_i, gxq, 0.0), 1, keepdims=True)
 
-        def acc3(ref, val):
-            idx = (pl.ds(j, 1), slice(None), slice(None))
-            pl.store(ref, idx, pl.load(ref, idx) + val[None])
+        for ref, val in ((dw0_ref, dw0_j), (db0_ref, db0_j),
+                         (dwo_ref, dwo_j)):
+            ref[pl.ds(j, 1)] += val[None]
+        for ref, val in ((dbo_ref, dbo_j), (dfi_ref, dfi_j),
+                         (dfo_ref, dfo_j), (dio_ref, dio_j)):
+            ref[pl.ds(j, 1), :] += val
+        return jnp.where(col == j, gx_j, acc_dx)             # column j of dx
 
-        def acc2(ref, val):
-            idx = (pl.ds(j, 1), slice(None))
-            pl.store(ref, idx, pl.load(ref, idx) + val)
-
-        acc3(dw0_ref, dw0_j)
-        acc3(db0_ref, db0_j)
-        acc3(dwo_ref, dwo_j)
-        acc2(dbo_ref, dbo_j)
-        acc2(dfi_ref, dfi_j)
-        acc2(dfo_ref, dfo_j)
-        acc2(dio_ref, dio_j)
-        return jax.lax.dynamic_update_slice_in_dim(acc_dx, gx_j, j, 1)
-
-    acc_dx = jax.lax.fori_loop(0, c_in, body,
-                               jnp.zeros((x.shape[0], c_in), jnp.float32))
+    acc_dx = jax.lax.fori_loop(0, x.shape[1], body,
+                               jnp.zeros(x.shape, jnp.float32))
     dx_ref[...] = acc_dx[None]
 
 
@@ -168,7 +159,7 @@ def lut_dense_bwd_fused(x, w0, b0, w_out, b_out, f_in, i_in, f_out, i_out, g,
 
     f32 = jnp.float32
     outs = pl.pallas_call(
-        functools.partial(_lut_dense_bwd_kernel, c_in=c_in),
+        _lut_dense_bwd_kernel,
         grid=grid,
         in_specs=[spec_x, spec_w, spec_w, spec_w, spec_q,
                   spec_q, spec_q, spec_q, spec_q, spec_g],

@@ -86,7 +86,7 @@ _INT32_MAX_WIDTH = 30
 
 
 def _x64_enabled() -> bool:
-    return bool(getattr(jax.config, "jax_enable_x64", False))
+    return bool(jax.config.jax_enable_x64)
 
 
 def _pick_dtype(max_width: int):
@@ -285,7 +285,6 @@ def compile_program(prog: DaisProgram, *, mesh=None,
                     packed: Optional[object] = None,
                     jit: bool = True,
                     block_batch: Optional[int] = None,
-                    interpret: Optional[bool] = None,
                     narrow: bool = True) -> ServeEngine:
     """Lower a DAIS program to a jitted accelerator engine.
 
@@ -308,8 +307,8 @@ def compile_program(prog: DaisProgram, *, mesh=None,
     (force the generic levelized runner).  Unavailable preferences degrade
     ``pallas -> fused -> generic``; ``packed`` optionally supplies a
     pre-packed :class:`~repro.kernels.lut_serve_pallas.PackedStages` (from
-    a v3 artifact bundle), and ``block_batch`` / ``interpret`` pass
-    through to the Pallas runner.  ``fuse_layers=False`` is the legacy
+    a v3 artifact bundle), and ``block_batch`` passes through to the
+    Pallas runner.  ``fuse_layers=False`` is the legacy
     spelling of ``engine="groups"``.
 
     ``mesh``: optional ``jax.sharding.Mesh`` — the batch axis of inputs and
@@ -370,8 +369,7 @@ def compile_program(prog: DaisProgram, *, mesh=None,
                 if packed is None:
                     packed = _pallas.pack_stages(stages, dtype)
                 run = _pallas.pallas_runner(packed, dtype, mesh,
-                                            block_batch=block_batch,
-                                            interpret=interpret)
+                                            block_batch=block_batch)
                 path, n_groups = "pallas", packed.n_stages()
                 n_launches, packed_bytes = 1, packed.table_bytes()
             except _pallas.PackError as e:

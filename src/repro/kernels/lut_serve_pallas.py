@@ -51,9 +51,12 @@ Execution (:func:`pallas_runner`)
 ---------------------------------
 Grid = 1-D over batch tiles (``block_batch`` rows per program instance,
 shrunk to the padded batch for small scheduler buckets).  The stage loop is
-statically unrolled inside the kernel; gather/output indices are baked in
-as constants, while tables, masks, shifts, biases and epilogue parameters
-arrive as full-array block inputs (VMEM-resident across the chain).  A
+statically unrolled inside the kernel, and every value in it is 2-D so that
+Mosaic lowers it: gather/output indices are baked in as static column
+slices, and a table lookup is a loop over the table's entries that selects
+each entry where the index matches.  Tables (entry-major, in their lane
+dtype), masks, shifts, biases and epilogue parameters arrive as full-array
+block inputs (VMEM-resident across the chain).  A
 second grid axis over stage width is deliberately absent: stages are
 all-to-all (every output column may read any input column), so a width
 tile would have to re-materialize the full inter-stage vector anyway —
@@ -62,10 +65,10 @@ it instead.  Bit-exactness reuses the same ``_shift_round`` /
 ``_requant_cols`` primitives as the fused engine and is gated by the same
 ``verify_engine`` before anything serves or is benchmarked.
 
-On non-TPU backends the kernel runs with ``interpret=True`` (under ``jit``
-this still compiles to XLA), so CPU CI executes the identical kernel
-logic; CPU speedups come from tile-resident intermediates and the packing
-optimizations above, not from Mosaic.
+On a TPU the kernel compiles through Mosaic; on any other backend it runs
+with ``interpret=True`` (under ``jit`` this still compiles to XLA), so CPU
+tests execute the identical kernel logic.  On a multi-device mesh the call
+runs under ``shard_map``, one batch shard per device.
 """
 
 from __future__ import annotations
@@ -85,6 +88,13 @@ from repro.kernels.lut_serve import (EpiOp, FusedStages, _requant_cols,
 # that a few stages of (TB, S, co) intermediates stay cache/VMEM-resident
 # (picked by sweeping 64..1024 at batch 1024 on the bench models)
 DEF_BLOCK_BATCH = 512
+
+# VMEM for one tile's intermediates.  Compiling pid-hybrid (ctx=100) for a
+# TPU v5e showed about a dozen live int32 copies of the widest stage's
+# (TB, S * lane-padded J*co) index/value blocks; a tile is sized so that
+# they fit this, inside the 16 MiB default scoped VMEM with the tables.
+DEF_TILE_VMEM = 8 << 20
+_LIVE_BLOCKS = 12
 
 # packed tables + stage constants must fit comfortably in VMEM (~16 MB on
 # current TPUs) with room for the batch tile and its intermediates
@@ -255,16 +265,36 @@ def pack_stages(stages: FusedStages, dtype: Optional[object] = None, *,
 # --------------------------------------------------------------------------- #
 # the kernel
 # --------------------------------------------------------------------------- #
+# Every value inside the kernel is 2-D (rows, flat columns): Mosaic lowers
+# static lane slices, lane concatenation and elementwise ops, but neither a
+# general gather nor a reshape that moves the lane axis.  So the per-site
+# column gather becomes static slices (its indices are compile-time
+# constants), and a "lut" stage lays its cells out flat as column
+# ``j * co + i`` of one site's (TB, J*co) block.
+
+def _rows_per_tile(dtype) -> int:
+    """Sublane rows of one native VMEM tile: 8 for 32-bit, 32 for int8."""
+    return 32 // np.dtype(dtype).itemsize
+
+
 def _const_arrays(packed: PackedStages, cdtype):
     """Flatten per-stage constants into one input list + name->index maps.
 
-    Tables keep their packed lane dtype (sign-extended inside the kernel);
-    every other array is coerced to the compute dtype so a bundle packed
-    under a different x64 setting still runs.
+    Each array is 2-D in the layout the kernel reads.  A table becomes
+    ``(E, J*co)`` (entry-major, cells flat) in its packed lane dtype,
+    padded to whole VMEM tiles with never-indexed zero rows, and is
+    sign-extended inside the kernel; everything else is a ``(rows,
+    S*co)``/``(1, J*co)`` row block coerced to the compute dtype, so a
+    bundle packed under a different x64 setting still runs.  Gather and
+    output columns are compile-time constants and stay out of the list.
     """
     ed = np.int32 if jnp.dtype(cdtype) == jnp.dtype(jnp.int32) else np.int64
     arrays: List[np.ndarray] = []
     entries: List[dict] = []
+
+    def row(a, rows=1):
+        return np.asarray(a, np.int64).astype(ed).reshape(rows, -1)
+
     for st in packed.stages:
         ent = {}
 
@@ -272,82 +302,120 @@ def _const_arrays(packed: PackedStages, cdtype):
             _ent[name] = len(arrays)
             arrays.append(a)
 
-        gather = np.asarray(st.gather, np.int64)
-        # static specializations the kernel builder reads back off the
-        # PackedStage: an identity gather (one site reading every incoming
-        # column in order — the LUT-Dense stack shape) is a pure reshape,
-        # and a gather that never hits the implicit zero column skips the
-        # zero-pad concat
-        identity = bool(
-            gather.size == st.n_cols
-            and np.array_equal(gather.ravel(), np.arange(st.n_cols)))
-        if not identity:
-            add("gather", gather.astype(np.int32))
-        add("bias", np.asarray(st.bias, np.int64).astype(ed))
+        add("bias", row(st.bias))
         if st.kind == "lut":
             if st.in_shift is not None:
-                add("in_shift", np.asarray(st.in_shift, np.int64).astype(ed))
-            add("mask", np.asarray(st.mask, np.int64).astype(ed))
-            add("table", np.asarray(st.table))        # keep the lane dtype
-        else:
-            add("coef", np.asarray(st.coef, np.int64).astype(ed))
+                add("in_shift", row(st.in_shift))
+            add("mask", row(st.mask))
+            table = np.asarray(st.table)                # (J, co, E) lane
+            flat = table.reshape(-1, table.shape[2]).T  # (E, J*co)
+            pad = -flat.shape[0] % _rows_per_tile(flat.dtype)
+            add("table", np.pad(flat, ((0, pad), (0, 0))))
         for m, e in enumerate(st.epilogue):
-            add(f"epi{m}", np.asarray(e.params, np.int64).astype(ed))
+            p = np.asarray(e.params, np.int64)
+            # REQUANT (S, co, 4) -> 4 rows (shift, width, signed, apply)
+            add(f"epi{m}", row(np.moveaxis(p, -1, 0), 4) if e.op == "REQUANT"
+                else row(p))
         entries.append(ent)
-    out_cols_idx = len(arrays)
-    arrays.append(np.asarray(packed.out_cols, np.int32))
-    return arrays, entries, out_cols_idx
+    return arrays, entries
 
 
-def _make_kernel(packed: PackedStages, entries, out_cols_idx: int):
+def _column(v, c: int, n_cols: int):
+    """Static column ``c`` of ``v`` as (TB, 1); ``n_cols`` is the zero pad."""
+    if c >= n_cols:
+        return jnp.zeros((v.shape[0], 1), v.dtype)
+    return v[:, c:c + 1]
+
+
+def _lookup(idxs, t_ref, dtype):
+    """``table[idx]`` per column for each (TB, C) index block in ``idxs``.
+
+    A loop over the table's entries, one VMEM tile of rows per step: each
+    row is compared against the indices and selected where it matches.
+    Exact for any lane dtype (the select copies the sign-extended entry),
+    and it needs no gather, which Mosaic lowers only in 2-D special cases.
+    """
+    rows = _rows_per_tile(t_ref.dtype)
+
+    def body(k, vals):
+        base = pl.multiple_of(k * rows, rows)
+        tile = t_ref[pl.ds(base, rows), :].astype(dtype)   # sign-extend
+        vals = list(vals)
+        for q in range(rows):
+            entry = tile[q:q + 1, :]                        # (1, C)
+            for s, idx in enumerate(idxs):
+                vals[s] = jnp.where(idx == base + q, entry, vals[s])
+        return tuple(vals)
+
+    init = tuple(jnp.zeros(idx.shape, dtype) for idx in idxs)
+    return jax.lax.fori_loop(0, t_ref.shape[0] // rows, body, init)
+
+
+def _make_kernel(packed: PackedStages, entries):
     """Build the mega-kernel body: the stage loop, statically unrolled."""
 
     def kernel(*refs):
         x_ref, consts, out_ref = refs[0], refs[1:-1], refs[-1]
         v = x_ref[...]                                  # (TB, n_cols0)
         for st, ent in zip(packed.stages, entries):
-            tb = v.shape[0]
-            if "gather" not in ent:                     # identity gather
-                g = v.reshape(tb, *st.gather.shape)     # (TB, S, J)
-            else:
-                if bool((np.asarray(st.gather) >= st.n_cols).any()):
-                    # implicit all-zero column at index n_cols (im2col pad)
-                    v = jnp.concatenate(
-                        [v, jnp.zeros((tb, 1), v.dtype)], axis=1)
-                g = v[:, consts[ent["gather"]][...]]    # (TB, S, J)
+            tb, dt = v.shape[0], v.dtype
+            gather = np.asarray(st.gather)
+            sites = [[_column(v, int(c), st.n_cols) for c in gather[s]]
+                     for s in range(st.n_sites)]
             if st.kind == "lut":
-                if st.in_shift is not None:
-                    code = _shift_round(g[..., None],
-                                        consts[ent["in_shift"]][...])
-                else:
-                    code = g[..., None]                 # grids already match
-                idx = code & consts[ent["mask"]][...]   # (TB, S, J, co)
-                table = consts[ent["table"]][...]       # (J, co, E) lane
                 j_n, co = st.mask.shape
-                jj = jax.lax.broadcasted_iota(jnp.int32, (j_n, co), 0)
-                ii = jax.lax.broadcasted_iota(jnp.int32, (j_n, co), 1)
-                vals = table[jj, ii, idx].astype(v.dtype)   # sign-extend
-                # pin the accumulator: under x64, integer sums otherwise
-                # promote to the default int64 and poison the int32 chain
-                acc = vals.sum(axis=2, dtype=v.dtype)   # (TB, S, co)
+                idxs = []
+                for cols in sites:                      # (TB, J*co) per site
+                    code = jnp.concatenate(
+                        [jnp.broadcast_to(c, (tb, co)) for c in cols], axis=1)
+                    if st.in_shift is not None:
+                        code = _shift_round(code, consts[ent["in_shift"]][...])
+                    idxs.append(code & consts[ent["mask"]][...])
+                per_site = []
+                for val in _lookup(idxs, consts[ent["table"]], dt):
+                    acc = val[:, :co]                   # Σ_j -> (TB, co)
+                    for j in range(1, j_n):
+                        acc = acc + val[:, j * co:(j + 1) * co]
+                    per_site.append(acc)
             else:
-                coef = consts[ent["coef"]][...]         # (S, J)
-                acc = (g * coef[None]).sum(axis=-1, dtype=v.dtype)[..., None]
-            acc = acc + consts[ent["bias"]][...][None]
+                coef = np.asarray(st.coef)
+                per_site = []
+                for s, cols in enumerate(sites):
+                    acc = jnp.zeros((tb, 1), dt)
+                    for c, k in zip(cols, coef[s]):
+                        if k:
+                            acc = acc + c * jnp.asarray(k, dt)
+                    per_site.append(acc)
+            acc = jnp.concatenate(per_site, axis=1) \
+                if len(per_site) > 1 else per_site[0]   # (TB, S*co)
+            acc = acc + consts[ent["bias"]][...]
             for m, epi in enumerate(st.epilogue):
                 p = consts[ent[f"epi{m}"]][...]
                 if epi.op == "REQUANT":
-                    res = _requant_cols(acc, p[..., 0][None], p[..., 1][None],
-                                        (p[..., 2] != 0)[None], epi.mode)
+                    res = _requant_cols(acc, p[0:1], p[1:2], p[2:3] != 0,
+                                        epi.mode)
                     if bool(np.all(np.asarray(epi.params)[..., 3] != 0)):
                         acc = res                       # statically all-apply
                     else:
-                        acc = jnp.where((p[..., 3] != 0)[None], res, acc)
+                        acc = jnp.where(p[3:4] != 0, res, acc)
                 else:                                   # CMUL
-                    acc = acc * p[None]
-            v = acc.reshape(tb, -1)
-        out_ref[...] = v[:, consts[out_cols_idx][...]]
+                    acc = acc * p
+            v = acc
+        outs = [_column(v, int(c), v.shape[1]) for c in packed.out_cols]
+        out_ref[...] = jnp.concatenate(outs, axis=1) if len(outs) > 1 \
+            else outs[0]
     return kernel
+
+
+def _max_tile(packed: PackedStages) -> int:
+    """Largest power-of-two batch tile whose intermediates fit
+    :data:`DEF_TILE_VMEM` (never below 8 rows, one sublane tile)."""
+    lanes = lambda n: -(-int(n) // 128) * 128
+    width = max((st.n_sites * lanes(st.mask.size if st.kind == "lut"
+                                    else st.c_out)
+                 for st in packed.stages), default=128)
+    rows = DEF_TILE_VMEM // (_LIVE_BLOCKS * 4 * width)
+    return max(8, 1 << (max(rows, 1).bit_length() - 1))
 
 
 def _full_spec(shape):
@@ -365,27 +433,29 @@ def pallas_runner(packed: PackedStages, dtype, mesh=None, *,
     """Close a :class:`PackedStages` over device constants -> runner fn.
 
     Returns ``run(x: (B, n_cols0) cdtype) -> (B, n_outputs)``, the
-    single-``pallas_call`` chain.  ``interpret=None`` auto-selects
-    interpret mode off-TPU so the same kernel logic runs everywhere.
+    single-``pallas_call`` chain.  ``interpret=None`` compiles the kernel
+    through Mosaic on a TPU and interprets it on any other backend, so the
+    same kernel logic runs everywhere and a Mosaic refusal is an error on
+    the chip.  On a multi-device ``mesh`` the call runs under ``shard_map``,
+    one batch shard per device over the mesh's DP axes (XLA cannot
+    partition a ``pallas_call`` itself).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     bb = int(block_batch or DEF_BLOCK_BATCH)
     if bb < 1:
         raise ValueError(f"block_batch must be >= 1, got {bb}")
-    consts_np, entries, out_cols_idx = _const_arrays(packed, dtype)
+    consts_np, entries = _const_arrays(packed, dtype)
     consts = [jnp.asarray(a) for a in consts_np]
     const_specs = [_full_spec(a.shape) for a in consts_np]
-    kernel = _make_kernel(packed, entries, out_cols_idx)
+    kernel = _make_kernel(packed, entries)
+    max_tile = _max_tile(packed)
     n_in, n_out = packed.n_cols0, len(packed.out_cols)
 
-    def run(x):
-        if mesh is not None:
-            from repro.parallel.sharding import constrain
-            x = constrain(x, mesh, "batch", None)
+    def call(x):
         b = x.shape[0]
         # small scheduler buckets shrink the tile instead of padding to it
-        tb = min(bb, _next_pow2(b))
+        tb = min(bb, max_tile, _next_pow2(b))
         pb = -b % tb
         xp = jnp.pad(x, ((0, pb), (0, 0))) if pb else x
         out = pl.pallas_call(
@@ -397,5 +467,23 @@ def pallas_runner(packed: PackedStages, dtype, mesh=None, *,
             out_shape=jax.ShapeDtypeStruct((b + pb, n_out), xp.dtype),
             interpret=interpret,
         )(xp, *consts)
+        return out[:b] if pb else out
+
+    if mesh is None or mesh.devices.size == 1:
+        return call
+
+    from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.sharding import batch_axes
+    axes = batch_axes(mesh)
+    n_shards = int(np.prod([mesh.shape[a] for a in axes]))
+    spec = P(axes, None)
+    sharded = jax.shard_map(call, mesh=mesh, in_specs=spec, out_specs=spec,
+                            check_vma=False)
+
+    def run(x):
+        b = x.shape[0]
+        pb = -b % n_shards               # every shard gets whole rows
+        out = sharded(jnp.pad(x, ((0, pb), (0, 0))) if pb else x)
         return out[:b] if pb else out
     return run

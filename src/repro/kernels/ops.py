@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-* auto-selects ``interpret=True`` off-TPU (this container is CPU-only; the
-  kernel body then runs as pure-Python/jnp and is validated against ref.py),
+* compiles the kernels through Mosaic on a TPU and auto-selects
+  ``interpret=True`` on any other backend (the kernel body then runs as
+  pure-Python/jnp and is validated against ref.py),
 * pairs the fused LUT-Dense forward (``lut_dense.py``) with the fused
   recompute backward (``lut_dense_bwd.py``) through a ``custom_vjp`` — both
   train and eval run kernel-side, with no (B, C_in, H, C_out) HBM

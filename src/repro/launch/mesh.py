@@ -2,20 +2,31 @@
 
 A function (not a module-level constant) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS before first init.
+
+Every mesh has ``Auto`` axes: the sharding rules of ``parallel/sharding.py``
+place arrays with ``with_sharding_constraint``, which refers only to Auto
+axes (``jax.make_mesh`` alone makes them Explicit).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """Whatever devices exist, as a 1D ('data',) mesh — used by examples."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((len(jax.devices()),), ("data",))

@@ -462,7 +462,11 @@ def _serve_selected(args, bundle_dir, selected, opt_prog, gate,
 
 
 def main(argv=None) -> None:
-    run(build_argparser().parse_args(argv))
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = build_argparser().parse_args(argv)
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

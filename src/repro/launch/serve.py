@@ -168,6 +168,8 @@ def main(argv=None) -> None:
                          "single-launch Pallas mega-kernel actually compiled")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.require_pallas and args.engine == "float":
         args.engine = "pallas"
     if args.engine in ("tables", "pallas"):
@@ -464,7 +466,6 @@ def serve_tier(args, mesh) -> None:
     concurrent multi-model load, not just aggregate counts.
     """
     from repro.kernels.lut_serve import input_code_bounds
-    from repro.parallel.sharding import replica_meshes
     from repro.serve.api import build, tier_from_built
     from repro.serve.scheduler import RejectedError, ServeConfig
     from repro.serve.tier import TierConfig
@@ -485,8 +486,6 @@ def serve_tier(args, mesh) -> None:
         built["default"] = BuiltEngine(engine=engine, prog=prog, oracle=prog,
                                        attestation=None)
 
-    placements = replica_meshes(mesh, args.replicas)
-    distinct = len({id(m) for m in placements})
     cfg = TierConfig(
         n_replicas=args.replicas,
         serve=ServeConfig(max_batch=args.max_batch,
@@ -495,10 +494,11 @@ def serve_tier(args, mesh) -> None:
                           slo_ms=args.slo_ms,
                           overload_policy=args.overload_policy))
     tier = tier_from_built(built, cfg)
-    print(f"[tier] up: {args.replicas} replicas over "
-          f"{mesh.devices.size} device(s) "
-          f"({'disjoint sub-meshes' if distinct > 1 else 'time-multiplexed'})"
-          f", models={sorted(built)}, max_queue={args.max_queue}, "
+    # every replica thread runs the one engine built on `mesh`, whose
+    # batches shard over all of the mesh's devices
+    print(f"[tier] up: {args.replicas} replica thread(s) sharing each "
+          f"model's engine on a {mesh.devices.size}-device mesh, "
+          f"models={sorted(built)}, max_queue={args.max_queue}, "
           f"policy={args.overload_policy}")
 
     # interleaved per-model open-loop traffic, absolute-deadline paced

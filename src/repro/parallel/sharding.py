@@ -163,34 +163,6 @@ def pad_batch(x, n_rows: int):
     return np.pad(np.asarray(x), pad)
 
 
-def replica_meshes(mesh: Optional[Mesh], n_replicas: int):
-    """Partition a mesh's devices into ``n_replicas`` per-replica meshes.
-
-    The serving tier (``repro.serve.tier``) runs a pool of engine replicas;
-    on a multi-device host each replica should own a disjoint slice of the
-    device fleet rather than all replicas contending for every chip.  When
-    the flattened device count divides evenly, each replica gets a 1-D
-    ``("data",)`` mesh over its contiguous slice — the same shape
-    ``launch.mesh.make_local_mesh`` builds, so ``shard_batch`` /
-    ``batch_dim_spec`` apply unchanged per replica.
-
-    When the devices don't divide (including the ubiquitous 1-device CPU
-    host) the replicas **time-multiplex**: every replica gets the original
-    mesh (or ``None``), and concurrency comes from jit's thread-safe
-    dispatch rather than device partitioning.
-    """
-    if n_replicas < 1:
-        raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
-    if mesh is None:
-        return [None] * n_replicas
-    devices = list(mesh.devices.flat)
-    if len(devices) < n_replicas or len(devices) % n_replicas:
-        return [mesh] * n_replicas
-    per = len(devices) // n_replicas
-    return [Mesh(np.asarray(devices[k * per:(k + 1) * per]), ("data",))
-            for k in range(n_replicas)]
-
-
 def heads_shardable(n_heads: int, mesh: Mesh) -> bool:
     axes = dict(zip(mesh.axis_names, mesh.devices.shape))
     return "model" in axes and n_heads % axes["model"] == 0

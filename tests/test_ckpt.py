@@ -98,7 +98,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.ckpt.store import CheckpointStore
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 ref = {{"a": jnp.zeros((4, 8)), "nested": {{"b": jnp.zeros(5, jnp.int32)}}}}
 sh = {{"a": NamedSharding(mesh, P("data", "model")),
       "nested": {{"b": NamedSharding(mesh, P())}}}}

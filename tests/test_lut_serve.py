@@ -229,7 +229,8 @@ def test_engine_with_mesh_sharding_bit_exact():
     """Batch-sharded serving (parallel/sharding.constrain) changes nothing."""
     layer = LUTDense(5, 6, hidden=4)
     prog = compile_sequential([layer], [layer.init(KEY)], IN_F, IN_I)
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh()
     engine = compile_program(prog, mesh=mesh)
     verify_engine(engine, prog, n_random=512)
 
@@ -303,7 +304,7 @@ def test_required_width_guards_transient_requant_overflow():
     prog.outputs = [r1]
     prog.output_f = [6]
     assert prog.max_width() <= 30 < prog.required_width()
-    if getattr(jax.config, "jax_enable_x64", False):
+    if jax.config.jax_enable_x64:
         engine = compile_program(prog)
         verify_engine(engine, prog, n_random=128)
     else:
@@ -331,7 +332,7 @@ def test_explicit_dtype_that_overflows_is_rejected():
 
     with pytest.raises(ValueError, match="overflow-wrap"):
         compile_program(prog, dtype=jnp.int32)
-    if not getattr(jax.config, "jax_enable_x64", False):
+    if not jax.config.jax_enable_x64:
         # the sneaky case: int64 was *requested* but x64-off jax would
         # hand back int32 arrays — the guard must see through the alias
         with pytest.raises(ValueError, match="X64"):

@@ -86,8 +86,9 @@ from repro.models.registry import build_model
 from repro.nn.params import param_shapes
 from repro.train import steps as steps_mod
 from repro.optim.adam import adam_init
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for arch in ("olmo_1b", "phi35_moe", "rwkv6_16b"):
     cfg = get_smoke(arch)
     model = build_model(cfg, mesh)
