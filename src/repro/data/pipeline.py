@@ -37,6 +37,7 @@ from typing import Callable, Iterable, Iterator, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 def stack_batches(get_batch: Callable[[int], dict], step: int, k: int):
@@ -58,7 +59,9 @@ class HostPrefetcher:
     ``segments`` is the chunk plan — ``(first_step, k)`` pairs, typically
     from ``train/loop.plan_chunks``.  ``depth`` bounds how many finished
     chunks may wait device-resident ahead of the consumer (2 = classic
-    double buffering: one in flight, one ready).
+    double buffering: one in flight, one ready).  The worker opens the
+    profiler spans ``hgq.prefetch.stack``, ``hgq.prefetch.put_device`` and
+    ``hgq.prefetch.full`` (waiting for room in the queue).
     """
 
     _DONE = ("done", None)
@@ -94,11 +97,14 @@ class HostPrefetcher:
             for step, k in self._segments:
                 if self._stop.is_set():
                     return
-                chunk = stack_batches(self._get_batch, step, k)
+                with TraceAnnotation("hgq.prefetch.stack"):
+                    chunk = stack_batches(self._get_batch, step, k)
                 if self._to_device:
-                    chunk = jax.device_put(chunk)
-                if not self._put(("chunk", (step, k, chunk))):
-                    return
+                    with TraceAnnotation("hgq.prefetch.put_device"):
+                        chunk = jax.device_put(chunk)
+                with TraceAnnotation("hgq.prefetch.full"):
+                    if not self._put(("chunk", (step, k, chunk))):
+                        return
         except BaseException as exc:  # noqa: BLE001 — re-raised in the consumer
             self._put(("error", exc))
         else:

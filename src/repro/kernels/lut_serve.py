@@ -67,12 +67,14 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 import warnings
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.dais import DaisProgram, OpGroup, _requant
 from repro.core.tables import LayerTables
@@ -222,23 +224,32 @@ class ServeEngine:
     n_launches: int = 0         # kernel launches per inference (pallas: 1;
                                 # fused/generic: one per stage/group)
     packed_table_bytes: int = 0  # lane-packed table bytes ("pallas" only)
+    # counters of this handle (not locked: one handle per thread, clone())
+    n_calls: int = 0            # run() calls
+    place_s: float = 0.0        # Σ seconds of cast + host->device + shard
 
     def run(self, x_codes) -> jax.Array:
         """(B, n_inputs) integer codes -> (B, n_outputs) integer codes.
 
         Same contract as ``DaisProgram.run`` (grids ``input_f`` in,
-        ``output_f`` out), executed on the default accelerator.
+        ``output_f`` out), executed on the default accelerator.  Returns
+        once the call is dispatched; the caller's fetch waits for it.
         """
-        x = jnp.asarray(x_codes, self.dtype)
-        if x.ndim == 1:
-            x = x[None]
-        # single-device meshes make shard_batch a pure no-op placement, but
-        # the host-side device_put still costs ~ms per call — material on the
-        # micro-batching serving path, so skip it
-        if self.mesh is not None and self.mesh.devices.size > 1:
-            from repro.parallel.sharding import shard_batch
-            x = shard_batch(x, self.mesh)
-        return self._runner(x)
+        with TraceAnnotation("hgq.engine.run"):
+            t0 = time.perf_counter()
+            with TraceAnnotation("hgq.engine.place"):
+                x = jnp.asarray(x_codes, self.dtype)
+                if x.ndim == 1:
+                    x = x[None]
+                # single-device meshes make shard_batch a pure no-op placement, but
+                # the host-side device_put still costs ~ms per call — material on the
+                # micro-batching serving path, so skip it
+                if self.mesh is not None and self.mesh.devices.size > 1:
+                    from repro.parallel.sharding import shard_batch
+                    x = shard_batch(x, self.mesh)
+            self.place_s += time.perf_counter() - t0
+            self.n_calls += 1
+            return self._runner(x)
 
     def run_float(self, x) -> np.ndarray:
         """Convenience mirror of ``DaisProgram.run_float``."""
@@ -254,10 +265,10 @@ class ServeEngine:
         jitted JAX callables are thread-safe and share one trace cache, so
         a clone costs nothing to make and nothing extra to warm — but it
         gives each serving-tier replica its own dataclass instance (own
-        identity, own future mutable counters) instead of N threads
-        aliasing one handle.  Used by ``repro.serve.tier.ServeTier``.
+        identity, own ``n_calls`` and ``place_s``, from zero) instead of N
+        threads aliasing one handle.  Used by ``repro.serve.tier.ServeTier``.
         """
-        return dataclasses.replace(self)
+        return dataclasses.replace(self, n_calls=0, place_s=0.0)
 
     def warm(self, batch_sizes) -> List[int]:
         """Populate the jit cache for every batch size in ``batch_sizes``.
@@ -1114,8 +1125,13 @@ def _prepare_stage(stage: FusedStage, dtype):
 
 
 def _fused_runner(stages: FusedStages, dtype, mesh):
-    """Close a :class:`FusedStages` over device constants -> runner fn."""
-    prepared = [_prepare_stage(st, dtype) for st in stages.stages]
+    """Close a :class:`FusedStages` over device constants -> runner fn.
+
+    Each stage's ops carry the name scope ``stage<i>_<kind>`` in the
+    compiled program's metadata, so an op can be traced to its stage.
+    """
+    prepared = [(f"stage{i}_{st.kind}", _prepare_stage(st, dtype))
+                for i, st in enumerate(stages.stages)]
     out_cols = np.asarray(stages.out_cols, np.int64)
 
     def _run(x):
@@ -1123,8 +1139,9 @@ def _fused_runner(stages: FusedStages, dtype, mesh):
             from repro.parallel.sharding import constrain
             x = constrain(x, mesh, "batch", None)
         v = x
-        for ex in prepared:
-            v = ex(v)
+        for scope, ex in prepared:
+            with jax.named_scope(scope):
+                v = ex(v)
         return v[:, out_cols]
     return _run
 

@@ -169,7 +169,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.obs import watch_gc
     enable_compile_cache()
+    watch_gc()
     if args.require_pallas and args.engine == "float":
         args.engine = "pallas"
     if args.engine in ("tables", "pallas"):
@@ -466,6 +468,7 @@ def serve_tier(args, mesh) -> None:
     concurrent multi-model load, not just aggregate counts.
     """
     from repro.kernels.lut_serve import input_code_bounds
+    from repro.obs import gc_stats
     from repro.serve.api import build, tier_from_built
     from repro.serve.scheduler import RejectedError, ServeConfig
     from repro.serve.tier import TierConfig
@@ -512,6 +515,7 @@ def serve_tier(args, mesh) -> None:
         ref = np.asarray(b.prog.run(codes), np.int64)
         work += [(name, codes[i], ref[i]) for i in range(per)]
     order = rng.permutation(len(work))
+    gc0 = gc_stats()
     t0 = time.monotonic()
     flights, n_rejected = [], 0
     for k, idx in enumerate(order):
@@ -530,6 +534,7 @@ def serve_tier(args, mesh) -> None:
                               ref):
             mismatches += 1
     wall = time.monotonic() - t0
+    gc_pause_s = gc_stats().pause_s - gc0.pause_s
     s = tier.stats()
     tier.stop()
     if mismatches:
@@ -543,6 +548,9 @@ def serve_tier(args, mesh) -> None:
           f"(batches={s.n_batches}, stolen={s.n_stolen}, "
           f"rejected={n_rejected}, shed={s.n_shed}, "
           f"deadline_misses={s.deadline_misses})")
+    print(f"[tier] mean queue wait={s.queue_wait_s / max(s.n_requests, 1) * 1e3:.3f} ms  "
+          f"mean flush={s.flush_s / max(s.n_batches, 1) * 1e3:.3f} ms  "
+          f"collector pauses={gc_pause_s * 1e3:.1f} ms")
     print(f"[tier] per-model: "
           f"{ {k: v for k, v in sorted(s.per_model.items())} } — every "
           f"response bit-exact vs its model's interpreter")

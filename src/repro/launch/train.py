@@ -86,6 +86,7 @@ def main(argv=None) -> None:
     from repro.core.ebops import BetaSchedule, beta_ramp_error
     from repro.data.synthetic import lm_batch
     from repro.models.registry import build_model
+    from repro.obs import gc_stats, watch_gc
     from repro.optim.adam import AdamConfig, cosine_restarts
     from repro.train.loop import chunked_train
     from repro.train.steps import TrainHParams, init_state, make_train_step
@@ -100,6 +101,7 @@ def main(argv=None) -> None:
         raise SystemExit(f"--beta-init/--beta-final: {err}")
     if args.chunk_steps < 1:
         raise SystemExit(f"--chunk-steps {args.chunk_steps}: must be >= 1")
+    watch_gc()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
@@ -186,7 +188,8 @@ def main(argv=None) -> None:
     if store:
         save(args.steps, blocking=True)
     final = float(metrics["loss"][-1])
-    print(f"[train] done: {args.steps} steps, final loss {final:.4f}")
+    print(f"[train] done: {args.steps} steps, final loss {final:.4f}, "
+          f"collector pauses {gc_stats().pause_s * 1e3:.1f} ms")
 
 
 if __name__ == "__main__":

@@ -39,7 +39,13 @@ Scheduling, in the order a request experiences it:
    futures.
 
 ``stats()`` returns a frozen :class:`TierStats` (per-model counts,
-stealing/shedding counters, deadline misses, latency percentiles).
+stealing/shedding counters, deadline misses, latency percentiles, and the
+seconds requests spent queued and batches spent in flight).
+
+Each replica thread opens ``hgq.tier.*`` profiler spans (``idle``,
+``coalesce``, and ``flush`` with its children ``pack``, ``fetch`` and
+``resolve``; the engine opens ``hgq.engine.run`` between them), so a
+trace of the tier shows what the host was doing while the device idled.
 """
 
 from __future__ import annotations
@@ -51,6 +57,7 @@ from concurrent.futures import Future
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.parallel.sharding import pad_batch
 from repro.serve.registry import ModelRegistry
@@ -96,6 +103,8 @@ class TierStats(_StatsView):
     max_ms: float = 0.0
     mean_batch_fill: float = 0.0
     pad_overhead: float = 0.0
+    queue_wait_s: float = 0.0    # Σ over served requests: taken - enqueued
+    flush_s: float = 0.0         # Σ over served batches: taken -> resolved
     per_model: Dict[str, int] = dataclasses.field(default_factory=dict)
     per_replica_batches: Tuple[int, ...] = ()
 
@@ -139,6 +148,8 @@ class ServeTier:
         self._batch_bucket: List[int] = []
         self._per_model: Dict[str, int] = {}
         self._per_replica_batches = [0] * n
+        self._queue_wait_s = 0.0
+        self._flush_s = 0.0
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> "ServeTier":
@@ -281,7 +292,8 @@ class ServeTier:
                 while not self._queues[k] and not self._closed:
                     if self.config.steal and self._steal_locked(k):
                         break
-                    self._work.wait(timeout=0.05)
+                    with TraceAnnotation("hgq.tier.idle"):
+                        self._work.wait(timeout=0.05)
                 if not self._queues[k]:
                     if self._closed:
                         return
@@ -295,11 +307,14 @@ class ServeTier:
                 if len(batch) < cfg.max_batch and not self._closed:
                     wait = flush_at - time.monotonic()
                     if wait > 0:
-                        self._work.wait(timeout=wait)
+                        with TraceAnnotation("hgq.tier.coalesce"):
+                            self._work.wait(timeout=wait)
                         continue     # re-sort and re-gather after the wait
                 for r in batch:
                     self._queues[k].remove(r)
-            self._run_batch(k, batch)
+                taken = time.monotonic()
+            with TraceAnnotation("hgq.tier.flush"):
+                self._run_batch(k, batch, taken)
 
     def _steal_locked(self, k: int) -> bool:
         """Move the oldest half of the deepest other queue to replica k."""
@@ -317,7 +332,9 @@ class ServeTier:
         self._n_stolen += len(take)
         return True
 
-    def _run_batch(self, k: int, batch: List[_TierRequest]) -> None:
+    def _run_batch(self, k: int, batch: List[_TierRequest],
+                   taken: float) -> None:
+        """Run one batch taken off the queue at monotonic ``taken``."""
         try:
             entry = self.registry.acquire(batch[0].model)
         except BaseException as e:   # model unregistered while queued
@@ -326,12 +343,16 @@ class ServeTier:
             with self._lock:
                 self._n_pending -= len(batch)
             return
+        served = False
         try:
             engine = self._handle(k, entry)
             n = len(batch)
             bucket = bucket_for(n, self.config.serve.max_batch)
-            x = pad_batch(np.stack([r.codes for r in batch]), bucket)
-            out = np.asarray(engine.run(x))[:n]
+            with TraceAnnotation("hgq.tier.pack"):
+                x = pad_batch(np.stack([r.codes for r in batch]), bucket)
+            y = engine.run(x)
+            with TraceAnnotation("hgq.tier.fetch"):
+                out = np.asarray(y)[:n]
             done = time.monotonic()
             with self._lock:
                 self._batch_fill.append(n)
@@ -342,8 +363,11 @@ class ServeTier:
                     self._per_model.get(entry.name, 0) + n)
                 self._deadline_misses += sum(
                     1 for r in batch if done > r.deadline)
-            for i, r in enumerate(batch):
-                r.future.set_result(out[i])
+                self._queue_wait_s += sum(taken - r.t_enqueue for r in batch)
+            served = True
+            with TraceAnnotation("hgq.tier.resolve"):
+                for i, r in enumerate(batch):
+                    r.future.set_result(out[i])
         except BaseException as e:
             for r in batch:
                 if not r.future.done():
@@ -352,6 +376,8 @@ class ServeTier:
             self.registry.release(entry)
             with self._work:
                 self._n_pending -= len(batch)
+                if served:
+                    self._flush_s += time.monotonic() - taken
                 self._work.notify_all()
 
     def _handle(self, k: int, entry) -> object:
@@ -384,6 +410,8 @@ class ServeTier:
                 n_shed=self._n_shed,
                 n_stolen=self._n_stolen,
                 deadline_misses=self._deadline_misses,
+                queue_wait_s=self._queue_wait_s,
+                flush_s=self._flush_s,
                 per_model=dict(self._per_model),
                 per_replica_batches=tuple(self._per_replica_batches),
             )

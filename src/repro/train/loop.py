@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, Iterable, Iterator, List, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 def plan_chunks(start: int, stop: int, chunk_steps: int,
@@ -103,6 +104,8 @@ class ChunkResult:
     metrics: Dict[str, np.ndarray]  # each metric stacked to shape (k, ...)
     dt_s: float                     # wall time, dispatch → host-visible
     compiled: bool                  # first use of this k: compile-inclusive
+    wait_s: float = 0.0             # waiting on the feed for this chunk
+    dispatch_s: float = 0.0         # the chunk_fn call: donation, enqueue
 
 
 def chunked_train(step_fn: Callable, params, opt_state,
@@ -118,25 +121,40 @@ def chunked_train(step_fn: Callable, params, opt_state,
     host-side numpy batch for one step and runs on the prefetch thread
     when ``prefetch=True``.  With ``donate=True`` the previous chunk's
     params/opt buffers are donated — hold only the latest ``ChunkResult``'s
-    state.
+    state.  Each chunk opens the profiler spans ``hgq.train.wait_chunk``,
+    ``hgq.train.dispatch`` and ``hgq.train.pull``; the first two are also
+    timed into ``wait_s`` and ``dispatch_s``.
     """
     from repro.data.pipeline import chunk_stream
 
     chunk_fn = make_chunked_step(step_fn, donate=donate)
     segments = plan_chunks(start, stop, chunk_steps, boundaries)
     seen_lengths: set = set()
-    for step, k, batches in chunk_stream(get_batch, segments,
-                                         prefetch=prefetch,
-                                         depth=prefetch_depth):
-        compiled = k not in seen_lengths
-        seen_lengths.add(k)
-        t0 = time.perf_counter()
-        params, opt_state, metrics = chunk_fn(params, opt_state, batches)
-        # ONE device→host transfer per chunk; blocks until the scan is done,
-        # which is what makes dt_s a real (watchdog-usable) boundary
-        metrics = {name: np.asarray(v) for name, v in metrics.items()}
-        dt_s = time.perf_counter() - t0
-        yield ChunkResult(step, k, params, opt_state, metrics, dt_s, compiled)
+    stream = chunk_stream(get_batch, segments, prefetch=prefetch,
+                          depth=prefetch_depth)
+    try:
+        while True:
+            t_wait = time.perf_counter()
+            with TraceAnnotation("hgq.train.wait_chunk"):
+                item = next(stream, None)
+            if item is None:
+                return
+            step, k, batches = item
+            compiled = k not in seen_lengths
+            seen_lengths.add(k)
+            t0 = time.perf_counter()
+            with TraceAnnotation("hgq.train.dispatch"):
+                params, opt_state, metrics = chunk_fn(params, opt_state, batches)
+            t1 = time.perf_counter()
+            # ONE device→host transfer per chunk; blocks until the scan is
+            # done, which is what makes dt_s a real (watchdog-usable) boundary
+            with TraceAnnotation("hgq.train.pull"):
+                metrics = {name: np.asarray(v) for name, v in metrics.items()}
+            dt_s = time.perf_counter() - t0
+            yield ChunkResult(step, k, params, opt_state, metrics, dt_s,
+                              compiled, wait_s=t0 - t_wait, dispatch_s=t1 - t0)
+    finally:
+        stream.close()
 
 
 def run_chunked(step_fn: Callable, params, opt_state,

@@ -146,7 +146,9 @@ def make_lut_train_step(layers, hp: TrainHParams = TrainHParams(),
             h = x
             auxes = []
             for idx, l in enumerate(layers):
-                h, a = l.apply(ps[f"l{idx}"], h, train=True)
+                # the layer's ops carry this scope in the program's metadata
+                with jax.named_scope(f"l{idx}_{type(l).__name__}"):
+                    h, a = l.apply(ps[f"l{idx}"], h, train=True)
                 auxes.append(scoped_updates(f"l{idx}", a))
             aux = merge_aux(*auxes)
             ce = -jnp.mean(jax.nn.log_softmax(h)[jnp.arange(h.shape[0]), y])

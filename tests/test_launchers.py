@@ -80,6 +80,20 @@ def test_serve_launcher_artifact_cache_and_loop(tmp_path):
     assert "hash mismatch" in (r3.stderr + r3.stdout)
 
 
+def test_serve_launcher_tier_reports_queue_flush_and_collector():
+    """--replicas 2: the tier's summary reads its counters and the
+    collector hook the launcher installs."""
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--engine", "tables",
+         "--lut-dims", "8,6,3", "--lut-hidden", "4", "--smoke", "--serve-loop",
+         "--replicas", "2", "--rate", "0", "--requests", "64",
+         "--max-batch", "16"],
+        env=ENV, cwd=REPO, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = next(x for x in r.stdout.splitlines() if "mean queue wait=" in x)
+    assert "mean flush=" in line and "collector pauses=" in line
+
+
 @pytest.mark.slow
 def test_serve_launcher_pid_hybrid():
     """--model pid-hybrid: the hybrid conv program compiles through the
@@ -104,6 +118,7 @@ def test_train_launcher_smoke():
         env=ENV, cwd=REPO, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "done: 4 steps" in r.stdout
+    assert "collector pauses" in r.stdout    # the hook the launcher installs
 
 
 @pytest.mark.slow

@@ -368,3 +368,44 @@ def test_two_real_models_served_concurrently_bit_exact():
     assert s.n_requests == 48 and s.n_batches >= 2
     # batches never mix models, so fills can't exceed the per-model counts
     assert s.mean_batch_fill <= 8
+
+
+# --------------------------------------------------------------------------- #
+# counters: seconds queued and seconds a flush takes
+# --------------------------------------------------------------------------- #
+class SlowEngine(EchoEngine):
+    def run(self, x):
+        time.sleep(0.002)
+        return super().run(x)
+
+
+def test_queue_and_flush_seconds_bound_the_tier_latency():
+    tier = _tier(SlowEngine())
+    s0 = tier.stats()
+    assert (s0.queue_wait_s, s0.flush_s) == (0.0, 0.0)
+    with tier:
+        for k in range(12):          # one at a time: one request a batch
+            tier.submit(np.full(4, k)).result(timeout=10)
+    s = tier.stats()
+    assert s.n_requests == s.n_batches == 12
+    queue = s.queue_wait_s / s.n_requests
+    flush = s.flush_s / s.n_batches
+    mean_latency = float(np.mean(tier._latencies_s))
+    assert 0 < queue <= mean_latency <= queue + flush
+    assert flush >= 0.002            # the engine's sleep is inside the flush
+
+
+class FailingEngine(EchoEngine):
+    def run(self, x):
+        raise RuntimeError("device lost")
+
+
+def test_queue_and_flush_seconds_count_only_served_batches():
+    tier = _tier(FailingEngine())
+    with tier:
+        fut = tier.submit(np.zeros(4))
+        with pytest.raises(RuntimeError, match="device lost"):
+            fut.result(timeout=10)
+    s = tier.stats()
+    assert (s.n_requests, s.n_batches) == (0, 0)
+    assert (s.queue_wait_s, s.flush_s) == (0.0, 0.0)

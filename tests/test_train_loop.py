@@ -223,6 +223,19 @@ def test_chunked_train_yields_real_boundaries():
         assert r.metrics["loss"].shape == (r.k,)
 
 
+@pytest.mark.parametrize("prefetch", [False, True])
+def test_chunk_results_time_the_feed_wait_and_the_dispatch(prefetch):
+    raw_step, init_fn, get_batch = _lut_setup()
+    p, o = init_fn(jax.random.PRNGKey(0))
+    results = list(chunked_train(raw_step, p, o, get_batch, 0, 10,
+                                 chunk_steps=4, prefetch=prefetch))
+    assert len(results) == 3
+    for r in results:
+        assert r.wait_s >= 0 and r.dispatch_s >= 0
+        assert r.dispatch_s <= r.dt_s       # dt_s is dispatch + metrics pull
+    assert not _prefetch_threads()          # the stream closed with the loop
+
+
 @pytest.mark.slow
 def test_train_launcher_chunked_crash_resume_vs_per_step(tmp_path):
     """Crash at step 5 — NOT aligned to --chunk-steps 4 — then resume;
