@@ -19,10 +19,13 @@ a fallback to the generic path logs its reason and records it on
    :class:`FusedStage`.  The layer's tables are composed **once** and
    shared by all spatial sites — a "lut" layer keeps its
    :class:`~repro.core.tables.LayerTables` and runs as per-site gather →
-   requant → batched table gather → Σ; an "hgq" layer's per-cell
-   REQUANT → CMUL → align chains are enumerated over all input codes into
-   an equivalent table (relu folds into a vectorized epilogue); window
-   sums and standalone relus become table-free gather/sum stages.  The op
+   requant → batched table gather → Σ; an "hgq" layer whose chains are
+   one REQUANT per input then constant multiplies runs as gather →
+   requant → integer multiply-accumulate (kind "mac"), and any other
+   "hgq" chain is enumerated over all input codes into an equivalent
+   table (relu folds into a vectorized epilogue); window sums and
+   standalone relus become table-free gather/sum stages.
+   ``ServeEngine.stage_kinds`` lists each stage's kind.  The op
    count scales with model *depth*, not instruction count — the ≥10× over
    the numpy interpreter in ``benchmarks/serve_bench.py``.
 
@@ -224,6 +227,8 @@ class ServeEngine:
     n_launches: int = 0         # kernel launches per inference (pallas: 1;
                                 # fused/generic: one per stage/group)
     packed_table_bytes: int = 0  # lane-packed table bytes ("pallas" only)
+    stage_kinds: Tuple[str, ...] = ()   # kind of each stage it runs
+                                        # (fused/pallas); () on "generic"
     # counters of this handle (not locked: one handle per thread, clone())
     n_calls: int = 0            # run() calls
     place_s: float = 0.0        # Σ seconds of cast + host->device + shard
@@ -366,7 +371,7 @@ def compile_program(prog: DaisProgram, *, mesh=None,
     input_widths = np.asarray([ins.reg.width for ins in in_instrs], np.int64)
 
     run, n_groups, path = None, 0, "generic"
-    n_launches, packed_bytes = 0, 0
+    n_launches, packed_bytes, kinds = 0, 0, ()
     downgrades: List[str] = []
     reason = ""
     if want in ("pallas", "fused") and stages is None:
@@ -383,12 +388,14 @@ def compile_program(prog: DaisProgram, *, mesh=None,
                                             block_batch=block_batch)
                 path, n_groups = "pallas", packed.n_stages()
                 n_launches, packed_bytes = 1, packed.table_bytes()
+                kinds = tuple(st.kind for st in packed.stages)
             except _pallas.PackError as e:
                 downgrades.append(f"pallas unavailable: {e}")
     if run is None and want in ("pallas", "fused"):
         if stages is not None:
             run, path = _fused_runner(stages, dtype, mesh), "fused"
             n_groups = n_launches = stages.n_stages()
+            kinds = tuple(st.kind for st in stages.stages)
         elif want == "fused":
             downgrades.append(f"fused unavailable: {reason}")
     if run is None:
@@ -411,7 +418,8 @@ def compile_program(prog: DaisProgram, *, mesh=None,
         input_f=list(prog.input_f), input_signed=list(prog.input_signed),
         input_widths=input_widths, output_f=list(prog.output_f),
         mesh=mesh, _runner=jax.jit(run) if jit else run,
-        n_launches=n_launches, packed_table_bytes=packed_bytes)
+        n_launches=n_launches, packed_table_bytes=packed_bytes,
+        stage_kinds=kinds)
 
 
 def _group_runner(prog: DaisProgram, dtype, mesh):
@@ -575,12 +583,15 @@ class FusedStage:
     ``j`` — the table is stored **once** and indexed by every site, which
     is the whole point of the shared-table lowering.  Kind "sum" is the
     table-free variant (window accumulation, standalone relu):
-    ``Σ_j sign * (v << shift)``.  Both add ``bias`` and then apply the
+    ``Σ_j sign * (v << shift)``.  Kind "mac" is an HGQ layer linear in its
+    requantised inputs: ``Σ_j requant_j(v) * weight[j, i]``, an integer
+    multiply-accumulate with no table (:func:`mac_as_lut` gives the
+    equivalent enumerated "lut" form).  All add ``bias`` and then apply the
     ``epilogue`` ops (e.g. an HGQ layer's relu clamp).  The stage output is
     ``(B, S, co)`` reshaped to the next stage's flat ``(B, S*co)``.
     """
 
-    kind: str                    # "lut" | "sum"
+    kind: str                    # "lut" | "sum" | "mac"
     gather: np.ndarray           # (S, J) int64; == n_cols -> zero column
     n_cols: int                  # incoming flat width
     bias: np.ndarray             # (S, co) int64
@@ -593,10 +604,18 @@ class FusedStage:
     # kind "sum"
     shifts: Optional[np.ndarray] = None     # (S, J) alignment shifts
     signs: Optional[np.ndarray] = None      # (S, J) in {-1, 0, +1}
-    # kind "lut", optional: (J, co, E) bool — entries the range analysis
-    # proves reachable.  Compile-time metadata only (the Pallas packer
-    # zeroes dead entries before lane selection); NOT part of the wire
-    # format, so bundles reload without it and simply skip narrowing.
+    # kind "mac"
+    in_fmt: Optional[np.ndarray] = None     # (J, 2) incoming (width, signed)
+    requant: Optional[np.ndarray] = None    # (J, 4) per position, for all
+                                            # outputs: (grid shift, width,
+                                            # signed, apply)
+    requant_mode: str = ""                  # overflow mode of the requants
+    weight: Optional[np.ndarray] = None     # (J, co) int64 folded weights
+    # kind "lut" (and "mac", for its enumerated form), optional: (J, co, E)
+    # bool — entries the range analysis proves reachable.  Compile-time
+    # metadata only (the Pallas packer zeroes dead entries before lane
+    # selection); NOT part of the wire format, so bundles reload without
+    # it and simply skip narrowing.
     live: Optional[np.ndarray] = None
 
     @property
@@ -883,13 +902,108 @@ def _chain_only_site(prog: DaisProgram, site) -> Optional[List[int]]:
     return chain
 
 
+def _enum_masks(widths, co: int) -> Tuple[np.ndarray, int]:
+    """(J, co) index masks and entry count of a table enumerated over
+    registers ``widths`` bits wide; :class:`_ComposeError` past the caps."""
+    j_n = len(widths)
+    if max(widths) > _MAX_ENUM_WIDTH:
+        raise _ComposeError(
+            f"operand register too wide to enumerate "
+            f"({max(widths)} > {_MAX_ENUM_WIDTH} bits)")
+    e_max = 1 << max(widths)
+    if j_n * co * e_max > _MAX_COMPOSED_ELEMS:
+        raise _ComposeError(
+            f"composed table too large ({j_n * co * e_max} entries)")
+    mask = np.repeat((np.int64(1) << np.asarray(widths, np.int64))[:, None]
+                     - 1, co, axis=1)
+    return mask, e_max
+
+
+def _enum_codes(width: int, signed: bool) -> np.ndarray:
+    """Every code of a ``width``-bit register, in table-index order (the
+    two's-complement pattern ``code & mask``)."""
+    e = np.arange(1 << width, dtype=np.int64)
+    return np.where(e >= (1 << width) // 2, e - (1 << width), e) \
+        if signed else e
+
+
+def _mac_fields(prog: DaisProgram, site, fmts, co: int) -> Optional[dict]:
+    """The "mac" fields of a stage linear in its requantised inputs, or None.
+
+    Eligible when every term chain is an optional REQUANT followed only by
+    CMULs, and every term that reads position ``j`` carries the same
+    REQUANT (or none), in one overflow mode: output ``i`` is then
+    ``Σ_j requant_j(v_j) * weight[j, i]``, each term's constant codes, sign
+    and alignment shift folded into its weight.  Exact in the engine's
+    wrapping integers, since the sum the program computes fits its dtype.
+    """
+    keys: List[Optional[tuple]] = [None] * len(fmts)
+    weight = [[0] * co for _ in fmts]
+    for i, (_epi, terms, _c) in enumerate(site):
+        for j, sign, shift, chain in terms:
+            head = chain[:1] if chain and \
+                prog.instrs[chain[0]].op == "REQUANT" else []
+            if shift < 0 or any(prog.instrs[r].op != "CMUL"
+                                for r in chain[len(head):]):
+                return None
+            key = tuple(prog.instrs[head[0]].args[1:]) if head else ()
+            if keys[j] is None:
+                keys[j] = key
+            elif keys[j] != key:
+                return None          # a per-cell requant: not one per position
+            w = sign << shift
+            for r in chain[len(head):]:
+                w *= int(prog.instrs[r].args[1])
+            weight[j][i] += w
+    modes = {k[3] for k in keys if k}
+    if len(modes) > 1 or any(abs(w) >= 1 << 63 for row in weight for w in row):
+        return None
+    requant = np.zeros((len(fmts), 4), np.int64)
+    for j, k in enumerate(keys):
+        if k:
+            f, ib, signed, _mode, src_f = k
+            requant[j] = (f - src_f, f + ib + (1 if signed else 0),
+                          int(bool(signed)), 1)
+    return dict(in_fmt=np.asarray([(w, int(s)) for _f, w, s in fmts], np.int64),
+                requant=requant, requant_mode=modes.pop() if modes else "SAT",
+                weight=np.asarray(weight, np.int64))
+
+
+def mac_as_lut(stage: FusedStage) -> FusedStage:
+    """The enumerated "lut" form of a "mac" stage.
+
+    Exactly the table :func:`_compose_enum_stage` builds when it enumerates
+    the same chains: row ``(j, i)`` holds ``weight[j, i] * requant_j(code)``
+    over every code of position ``j``'s incoming register.  The Pallas
+    packer runs "mac" stages in this form.  Raises :class:`_ComposeError`
+    past the enumeration caps.
+    """
+    j_n, co = stage.weight.shape
+    widths = [int(w) for w in stage.in_fmt[:, 0]]
+    mask, e_max = _enum_masks(widths, co)
+    table = np.zeros((j_n, co, e_max), np.int64)
+    for j, (width, signed) in enumerate(stage.in_fmt):
+        q = _enum_codes(int(width), bool(signed))
+        shift, w, sgn, apply = (int(v) for v in stage.requant[j])
+        if apply:
+            q = _requant(q, 0, shift, w - shift - sgn, bool(sgn),
+                         stage.requant_mode)
+        table[j, :, :len(q)] = stage.weight[j][:, None] * q[None]
+    zeros = np.zeros((j_n, co), np.int64)
+    return dataclasses.replace(
+        stage, kind="lut", in_shift=zeros, mask=mask, table=table,
+        out_shift=zeros, in_fmt=None, requant=None, requant_mode="",
+        weight=None)
+
+
 def _compose_enum_stage(prog: DaisProgram, segs, gather, fmts) -> FusedStage:
     """An "hgq"/"acc"/"relu" layer: decompose each output into a sum of
     univariate chains, then the cheapest faithful stage: table-free "sum"
     (every term a bare register — window accumulation), chain-as-epilogue
-    (standalone relu), or each chain enumerated over its input register's
-    code space into a site-shared table ("lut" semantics without
-    LayerTables).
+    (standalone relu), an integer multiply-accumulate "mac" (every chain
+    linear in one requant per position, :func:`_mac_fields`), or each chain
+    enumerated over its input register's code space into a site-shared
+    table ("lut" semantics without LayerTables).
     """
     n_sites, j_n = gather.shape
     co = len(segs[0].out_regs)
@@ -947,24 +1061,16 @@ def _compose_enum_stage(prog: DaisProgram, segs, gather, fmts) -> FusedStage:
         return FusedStage(kind="sum", gather=gather, n_cols=0, bias=bias,
                           epilogue=epilogue, shifts=shifts, signs=signs)
 
+    fmts = _stage_fmts(fmts)
+    mac = _mac_fields(prog, site0, fmts, co)
+    if mac is not None:
+        return FusedStage(kind="mac", gather=gather, n_cols=0, bias=bias,
+                          epilogue=epilogue, **mac)
+
     # enumerated tables: one (J, co, E) table shared by every site
-    widths = [w for _f, w, _s in _stage_fmts(fmts)]
-    if max(widths) > _MAX_ENUM_WIDTH:
-        raise _ComposeError(
-            f"operand register too wide to enumerate "
-            f"({max(widths)} > {_MAX_ENUM_WIDTH} bits)")
-    e_max = 1 << max(widths)
-    if j_n * co * e_max > _MAX_COMPOSED_ELEMS:
-        raise _ComposeError(
-            f"composed table too large ({j_n * co * e_max} entries)")
+    mask, e_max = _enum_masks([w for _f, w, _s in fmts], co)
     table = np.zeros((j_n, co, e_max), np.int64)
-    mask = np.zeros((j_n, co), np.int64)
-    codes = []
-    for j, (_f, w, signed) in enumerate(fmts):
-        e = np.arange(1 << w, dtype=np.int64)
-        codes.append(np.where(e >= (1 << w) // 2, e - (1 << w), e)
-                     if signed else e)
-        mask[j, :] = (1 << w) - 1
+    codes = [_enum_codes(w, signed) for _f, w, signed in fmts]
     for i, (_epi, terms, _c) in enumerate(site0):
         for j, sign, shift, chain in terms:
             v = _eval_chain(prog, chain, codes[j])
@@ -984,7 +1090,8 @@ def _shift_round_scalar(v: int, shift: int) -> int:
     return _round_half_even(v, -shift)
 
 
-def _stage_live(ranges, segs, stage: FusedStage) -> np.ndarray:
+def _stage_live(ranges, segs, in_shift: np.ndarray, mask: np.ndarray,
+                e_max: int) -> np.ndarray:
     """(J, co, E) bool mask of table entries any site can actually index.
 
     Per cell ``(j, i)`` the runtime index is
@@ -998,18 +1105,34 @@ def _stage_live(ranges, segs, stage: FusedStage) -> np.ndarray:
     packed lane dtype wide.
     """
     from repro.core.analysis import index_window
-    j_n, co, e_max = stage.table.shape
+    j_n, co = mask.shape
     live = np.zeros((j_n, co, e_max), bool)
     for seg in segs:
         for j, r in enumerate(seg.in_regs):
             lo, hi = ranges.range(r)
             for i in range(co):
-                sh = int(stage.in_shift[j, i])
-                size = int(stage.mask[j, i]) + 1
+                sh = int(in_shift[j, i])
+                size = int(mask[j, i]) + 1
                 win = index_window(_shift_round_scalar(lo, sh),
                                    _shift_round_scalar(hi, sh), size)
                 live[j, i, :size] |= win
     return live
+
+
+def _stage_live_of(ranges, segs, stage: FusedStage) -> Optional[np.ndarray]:
+    """:func:`_stage_live` of a stage's table, or of a "mac" stage's
+    enumerated form when that is within the caps; None for other kinds."""
+    if stage.kind == "lut":
+        return _stage_live(ranges, segs, stage.in_shift, stage.mask,
+                           stage.table.shape[2])
+    if stage.kind == "mac":
+        try:
+            mask, e_max = _enum_masks([int(w) for w in stage.in_fmt[:, 0]],
+                                      stage.c_out)
+        except _ComposeError:
+            return None
+        return _stage_live(ranges, segs, np.zeros_like(mask), mask, e_max)
+    return None
 
 
 def compose_fused_stages(prog: DaisProgram, dtype: Optional[object] = None,
@@ -1023,8 +1146,9 @@ def compose_fused_stages(prog: DaisProgram, dtype: Optional[object] = None,
     surface ``reason``.
 
     ``ranges``: optional :class:`~repro.core.analysis.ValueRanges` for
-    ``prog`` — each "lut" stage then carries a ``live`` entry mask
-    (:func:`_stage_live`) that the Pallas packer uses to narrow lanes.
+    ``prog`` — each "lut" stage (and each "mac" stage, over its enumerated
+    form) then carries a ``live`` entry mask (:func:`_stage_live`) that the
+    Pallas packer uses to narrow lanes.
     """
     if dtype is None:
         try:
@@ -1058,8 +1182,8 @@ def compose_fused_stages(prog: DaisProgram, dtype: Optional[object] = None,
             else:
                 stage = _compose_enum_stage(prog, segs, gather, fmts)
             stage.n_cols = n_cols
-            if ranges is not None and stage.table is not None:
-                stage.live = _stage_live(ranges, segs, stage)
+            if ranges is not None:
+                stage.live = _stage_live_of(ranges, segs, stage)
             stages.append(stage)
             colmap = {r: s * stage.c_out + i
                       for s, seg in enumerate(segs)
@@ -1103,6 +1227,22 @@ def _prepare_stage(stage: FusedStage, dtype):
             idx = code & mask
             vals = table[jj, ii, idx] << out_shift
             return vals.sum(axis=2)                         # (B, S, co)
+    elif stage.kind == "mac":
+        rq = np.asarray(stage.requant, np.int64)
+        shift = jnp.asarray(rq[:, 0], dtype)                # (J,)
+        width = jnp.asarray(rq[:, 1], dtype)
+        signed = jnp.asarray(rq[:, 2] != 0)
+        apply = None if rq[:, 3].all() else jnp.asarray(rq[:, 3] != 0)
+        # wraps like the engine's arithmetic: exact, as the sum fits dtype
+        weight = jnp.asarray(np.asarray(stage.weight, np.int64)
+                             .astype(np.dtype(dtype)))      # (J, co)
+        mode = stage.requant_mode
+
+        def body(g):                                        # g: (B, S, J)
+            q = _requant_cols(g, shift, width, signed, mode)
+            if apply is not None:
+                q = jnp.where(apply, q, g)
+            return (q[..., None] * weight).sum(axis=2)      # (B, S, co)
     else:
         shifts = jnp.asarray(stage.shifts, dtype)[None]     # (1, S, J)
         signs = jnp.asarray(stage.signs, dtype)[None]

@@ -33,6 +33,10 @@ The compile-time lowering from ``FusedStages``, done once per engine:
   saturation rows holding the largest-magnitude codes — exactly the values
   that force a wider lane — so proving them dead is what turns an int16
   table into an int8 one (``docs/ir.md``).
+* **"mac" stages run enumerated** — an HGQ stage the fused engine runs
+  as an integer multiply-accumulate is expanded back into its enumerated
+  table (:func:`~repro.kernels.lut_serve.mac_as_lut`) and packed as a
+  "lut" stage.
 * **in-shift elision** — stages whose per-cell input grids already match
   (every ``in_shift == 0`` — all enumerated HGQ stages, and LUT stages
   whose incoming grid equals the table grid) statically skip the
@@ -81,8 +85,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.lut_serve import (EpiOp, FusedStages, _requant_cols,
-                                     _shift_round)
+from repro.kernels.lut_serve import (EpiOp, FusedStages, _ComposeError,
+                                     _requant_cols, _shift_round, mac_as_lut)
 
 # default batch tile: big enough to amortize the grid step, small enough
 # that a few stages of (TB, S, co) intermediates stay cache/VMEM-resident
@@ -206,6 +210,11 @@ def pack_stages(stages: FusedStages, dtype: Optional[object] = None, *,
         else np.int64
     packed: List[PackedStage] = []
     for st in stages.stages:
+        if st.kind == "mac":
+            try:
+                st = mac_as_lut(st)
+            except _ComposeError as e:
+                raise PackError(f"mac stage cannot run enumerated: {e}")
         bias = np.asarray(st.bias, np.int64).astype(ed)
         epis = [EpiOp(op=e.op, mode=e.mode,
                       params=np.asarray(e.params, np.int64))

@@ -64,7 +64,8 @@ def live_table_stats(prog: DaisProgram, ranges) -> Optional[dict]:
 
     ``None`` when the program does not fuse (no composed tables to
     narrow).  This is the quantity the Pallas packer's lane narrowing
-    consumes; ``launch/pareto.py`` records it per frontier point.
+    consumes — a "mac" stage counts as the enumerated table it packs to;
+    ``launch/pareto.py`` records it per frontier point.
     """
     from repro.kernels.lut_serve import compose_fused_stages
 
@@ -73,11 +74,12 @@ def live_table_stats(prog: DaisProgram, ranges) -> Optional[dict]:
         return None
     total = live = 0
     for st in stages.stages:
-        if st.table is None:
+        entries = st.table if st.table is not None else st.live
+        if entries is None:
             continue
-        total += int(st.table.size)
+        total += int(entries.size)
         live += int(st.live.sum()) if st.live is not None \
-            else int(st.table.size)
+            else int(entries.size)
     if total == 0:
         return None
     return {"table_entries": total, "live_entries": live}

@@ -19,7 +19,12 @@ atomic ``.npz``:
 
 Format versions (negotiated by :func:`load_artifact`):
 
-* **v3** (current) — v2 plus the ``packed/*`` payload: the Pallas
+* **v4** (current) — v3 plus the "mac" stage kind (an HGQ layer run as
+  an integer multiply-accumulate): its per-position requant parameters,
+  incoming register formats and folded weights under ``fused/*``.  Its
+  ``packed/*`` entry is the enumerated table it packs to.  A v3 reader
+  refuses v4 bundles by version.
+* **v3** (read-only) — v2 plus the ``packed/*`` payload: the Pallas
   mega-kernel's bit-packed table layout
   (:class:`~repro.kernels.lut_serve_pallas.PackedStages` — out-shift
   folded, lane-dtype tables, sum-stage coefficients), so an
@@ -72,15 +77,15 @@ import numpy as np
 from repro.core.dais import _MODE_CODES, DaisProgram
 from repro.kernels.lut_serve import (EpiOp, FusedStage, FusedStages,
                                      ServeEngine, compile_program,
-                                     compose_fused_stages)
+                                     compose_fused_stages, mac_as_lut)
 from repro.kernels.lut_serve_pallas import (PackedStage, PackedStages,
                                             PackError, pack_stages)
 
 logger = logging.getLogger(__name__)
 
-FORMAT_VERSION = 3
-_SUPPORTED_VERSIONS = (1, 2, 3)
-_STAGE_KINDS = ("lut", "sum")
+FORMAT_VERSION = 4
+_SUPPORTED_VERSIONS = (1, 2, 3, 4)
+_STAGE_KINDS = ("lut", "sum", "mac")
 _EPI_OPS = ("REQUANT", "CMUL")
 
 
@@ -131,6 +136,12 @@ def _data_arrays(prog: DaisProgram,
                 arrays[p + "mask"] = np.asarray(st.mask, np.int64)
                 arrays[p + "table"] = np.asarray(st.table, np.int64)
                 arrays[p + "out_shift"] = np.asarray(st.out_shift, np.int64)
+            elif st.kind == "mac":
+                arrays[p + "in_fmt"] = np.asarray(st.in_fmt, np.int64)
+                arrays[p + "requant"] = np.asarray(st.requant, np.int64)
+                arrays[p + "requant_mode"] = np.asarray(
+                    [_MODE_CODES.index(st.requant_mode)], np.int64)
+                arrays[p + "weight"] = np.asarray(st.weight, np.int64)
             else:
                 arrays[p + "shifts"] = np.asarray(st.shifts, np.int64)
                 arrays[p + "signs"] = np.asarray(st.signs, np.int64)
@@ -174,6 +185,8 @@ def _packed_from_arrays(arrays: Dict[str, np.ndarray],
     out = []
     for k, st in enumerate(stages.stages):
         p = f"packed/stage{k}_"
+        if st.kind == "mac":             # packed as its enumerated form
+            st = mac_as_lut(st)
         common = dict(kind=st.kind, gather=np.asarray(st.gather, np.int64),
                       n_cols=st.n_cols, bias=np.asarray(st.bias, np.int64),
                       epilogue=[EpiOp(op=e.op, mode=e.mode,
@@ -194,7 +207,7 @@ def _packed_from_arrays(arrays: Dict[str, np.ndarray],
 
 
 def _stages_from_arrays(arrays: Dict[str, np.ndarray]) -> FusedStages:
-    """Rebuild the v2 stage IR written by :func:`_data_arrays`."""
+    """Rebuild the stage IR (v2 on) written by :func:`_data_arrays`."""
     n = int(arrays["fused/n_stages"][0])
     stages = []
     for k in range(n):
@@ -214,6 +227,12 @@ def _stages_from_arrays(arrays: Dict[str, np.ndarray]) -> FusedStages:
                 **common, in_shift=arrays[p + "in_shift"],
                 mask=arrays[p + "mask"], table=arrays[p + "table"],
                 out_shift=arrays[p + "out_shift"]))
+        elif kind == "mac":
+            stages.append(FusedStage(
+                **common, in_fmt=arrays[p + "in_fmt"],
+                requant=arrays[p + "requant"],
+                requant_mode=_MODE_CODES[int(arrays[p + "requant_mode"][0])],
+                weight=arrays[p + "weight"]))
         else:
             stages.append(FusedStage(
                 **common, shifts=arrays[p + "shifts"],
@@ -286,7 +305,7 @@ class LoadedArtifact:
     stages: Optional[FusedStages]
     meta: dict
     content_hash: str    # recomputed at load == meta["content_hash"]
-    packed: Optional[PackedStages] = None   # v3 Pallas payload
+    packed: Optional[PackedStages] = None   # Pallas payload (v3 on)
 
     @property
     def attestation(self) -> Optional[dict]:

@@ -19,9 +19,11 @@ from repro.core.hgq_layers import HGQDense
 from repro.core.lut_layers import LUTDense
 from repro.core.quant import QuantConfig, quantize_to_int
 from repro.core.tables import extract_tables
-from repro.kernels.lut_serve import (_requant_cols, compile_program,
-                                     input_code_bounds, lower_tables,
-                                     verify_engine)
+from repro.kernels.lut_serve import (EnginePathWarning, _requant_cols,
+                                     compile_program, input_code_bounds,
+                                     lower_tables, verify_engine)
+
+from _hgq_progs import mixed_linear_prog, nonlinear_prog, per_cell_requant_prog
 
 KEY = jax.random.PRNGKey(11)
 IN_F, IN_I = 4, 2
@@ -206,13 +208,117 @@ def test_mixed_epilogue_passthrough_channel_not_clamped():
 
 def test_fuse_fallback_reason_wide_operand():
     """Un-enumerable HGQ operand widths must fall back *loudly*: the reason
-    is logged and recorded on the engine, never a silent path switch."""
-    h1 = HGQDense(3, 2)
-    prog = compile_sequential([h1], [h1.init(KEY)], input_f=18, input_i=6)
+    is logged and recorded on the engine, never a silent path switch.  A
+    24-bit operand under a non-linear REQUANT → CMUL → REQUANT chain fits
+    neither the "mac" form nor an enumerated table."""
+    prog = nonlinear_prog(width=24)
     engine = compile_program(prog)
     assert engine.path == "generic" and not engine.fused
     assert "enumerate" in engine.fuse_reason
+    assert engine.stage_kinds == ()
     verify_engine(engine, prog, n_random=256)
+
+
+def test_wide_linear_hgq_runs_as_mac():
+    """A 24-bit linear HGQDense enumerates nothing as a "mac" stage, so it
+    fuses past the enumeration cap; the Pallas path, which packs the
+    enumerated form, degrades to it loudly."""
+    h1 = HGQDense(3, 2)
+    prog = compile_sequential([h1], [h1.init(KEY)], input_f=18, input_i=6)
+    engine = compile_program(prog)
+    assert engine.path == "fused" and engine.fuse_reason == ""
+    assert engine.stage_kinds == ("mac",)
+    verify_engine(engine, prog, n_random=256)
+    with pytest.warns(EnginePathWarning, match="enumerate"):
+        pallas = compile_program(prog, engine="pallas")
+    assert pallas.path == "fused" and pallas.stage_kinds == ("mac",)
+
+
+def test_hgq_conv_front_composes_to_mac():
+    """The PID shape's HGQ conv front (one shared REQUANT per position, one
+    CMUL per term, relu) runs as an integer multiply-accumulate, bit-exact
+    against the interpreter and the generic engine."""
+    from repro.core.hgq_layers import HGQConv1D
+    from repro.core.lower import GraphInput, ModelGraph, WindowSum, lower
+    from repro.core.lut_layers import LUTConv1D
+
+    front = HGQConv1D(c_in=1, c_out=4, kernel=5, stride=5, activation="relu")
+    lc = LUTConv1D(c_in=4, c_out=3, kernel=3, padding="SAME", hidden=4)
+    head = LUTDense(3, 1, hidden=4)
+    ks = jax.random.split(jax.random.PRNGKey(23), 3)
+    params = [front.init(ks[0]), lc.init(ks[1]), head.init(ks[2])]
+    # 9-bit inputs (i=4): the front's requant (f=6, i=3) saturates at both
+    # ends of the input range
+    graph = ModelGraph(GraphInput((20, 1), IN_F, 4),
+                       [front, lc, head, WindowSum()])
+    prog = lower(graph, params + [None])
+
+    fused = compile_program(prog)
+    assert fused.path == "fused" and fused.fuse_reason == ""
+    assert fused.stage_kinds == ("mac", "lut", "lut", "sum")
+    generic = compile_program(prog, fuse_layers=False)
+    # random rows, then rows at both ends of every input's range and
+    # alternating between them
+    lo, hi = input_code_bounds(prog)
+    alt = np.where(np.arange(len(lo)) % 2 == 0, lo, hi)
+    codes = np.concatenate([
+        np.random.default_rng(9).integers(lo, hi + 1, (256, len(lo))),
+        np.stack([lo, hi, alt, lo + hi - alt])])
+    ref = prog.run(codes)
+    for eng in (fused, generic):
+        np.testing.assert_array_equal(
+            np.asarray(jax.device_get(eng.run(codes)), np.int64), ref)
+    verify_engine(fused, prog, n_random=256)
+
+
+def test_linear_chains_with_bare_positions_run_as_mac():
+    """Bare CMUL and bare-register terms next to a shared requant: still a
+    "mac" stage (the requant applied where the program has one), exact over
+    the whole input space."""
+    prog = mixed_linear_prog()
+    engine = compile_program(prog)
+    assert engine.path == "fused" and engine.stage_kinds == ("mac",)
+    stats = verify_engine(engine, prog, n_random=64, exhaustive_limit=1 << 16)
+    assert stats["exhaustive"] == 1 << 16
+
+
+@pytest.mark.parametrize("build", [per_cell_requant_prog, nonlinear_prog],
+                         ids=["per_cell_requant", "nonlinear"])
+def test_non_mac_chains_stay_enumerated(build):
+    """A position requantised differently per output, or a chain with a
+    non-linear step, keeps its enumerated "lut" table."""
+    prog = build()
+    engine = compile_program(prog)
+    assert engine.path == "fused" and engine.stage_kinds == ("lut",)
+    stats = verify_engine(engine, prog, n_random=64, exhaustive_limit=1 << 16)
+    assert stats["exhaustive"] == 1 << 16
+
+
+@pytest.mark.parametrize("model", ["pid", "jsc"])
+def test_default_engine_stage_kinds(model):
+    """With the default EngineSpec the PID hybrid runs its HGQ front as
+    "mac" and the rest as before; a LUT-Dense stack runs no "mac" stage."""
+    from repro.serve.api import EngineSpec, build
+
+    if model == "pid":
+        from repro.core.lower import lower
+        from repro.models.pid import (build_pid_graph, build_pid_layers,
+                                      init_pid_params)
+        layers = build_pid_layers()
+        params = init_pid_params(layers, jax.random.PRNGKey(0))
+        prog = lower(build_pid_graph(layers, n_samples=100),
+                     [*params, None])
+        want = ("mac", "lut", "lut", "lut", "sum")
+    else:
+        layers = [LUTDense(16, 20, hidden=8, use_batchnorm=True),
+                  LUTDense(20, 5, hidden=8)]
+        keys = jax.random.split(KEY, 2)
+        prog = compile_sequential(
+            layers, [l.init(k) for l, k in zip(layers, keys)], 4, 3)
+        want = ("lut", "lut")
+    engine = build(prog, EngineSpec(n_random=256)).engine
+    assert engine.path == "fused"
+    assert engine.stage_kinds == want
 
 
 def test_engine_run_float_matches_interpreter():

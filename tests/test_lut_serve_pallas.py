@@ -6,7 +6,7 @@ interpreter and the fused per-stage engine code-for-code — exhaustively on
 small input spaces, randomly on wide ones, on the hybrid PID conv shape,
 and on DCE-sliced programs with pruned table rows — while every path
 downgrade surfaces as a compile-time :class:`EnginePathWarning`, and the
-packed layout round-trips through the format-v3 artifact bundle.
+packed layout round-trips through the artifact bundle (format v3 on).
 
 On CPU the kernel runs with ``interpret=True`` (auto-selected off-TPU), so
 these tests execute the identical kernel logic CI ships.
@@ -30,6 +30,8 @@ from repro.kernels.lut_serve import (EnginePathWarning, compile_program,
 from repro.kernels import lut_serve_pallas
 from repro.kernels.lut_serve_pallas import (PackError, pack_stages,
                                             pallas_runner)
+
+from _hgq_progs import nonlinear_prog
 
 KEY = jax.random.PRNGKey(11)
 IN_F, IN_I = 4, 2
@@ -87,8 +89,7 @@ def test_two_layer_random_wide_bit_exact():
     _three_way(prog, codes)
 
 
-def test_hybrid_conv_graph_bit_exact():
-    """The PID shape: HGQ conv front, shared-table LUT convs, window sum."""
+def _hybrid_conv_prog():
     from repro.core.hgq_layers import HGQConv1D
     from repro.core.lower import GraphInput, ModelGraph, WindowSum, lower
     from repro.core.lut_layers import LUTConv1D
@@ -99,8 +100,13 @@ def test_hybrid_conv_graph_bit_exact():
     ks = jax.random.split(KEY, 3)
     graph = ModelGraph(GraphInput((16, 1), IN_F, IN_I),
                        [front, lc, head, WindowSum()])
-    prog = lower(graph, [front.init(ks[0]), lc.init(ks[1]),
+    return lower(graph, [front.init(ks[0]), lc.init(ks[1]),
                          head.init(ks[2]), None])
+
+
+def test_hybrid_conv_graph_bit_exact():
+    """The PID shape: HGQ conv front, shared-table LUT convs, window sum."""
+    prog = _hybrid_conv_prog()
     lo, hi = input_code_bounds(prog)
     codes = np.random.default_rng(5).integers(lo, hi + 1, (256, len(lo)))
     engine = _three_way(prog, codes)
@@ -168,6 +174,51 @@ def test_lane_packing_shrinks_tables():
     assert packed.resident_bytes() >= packed.table_bytes()
 
 
+def _packed_arrays(packed):
+    """Every field of a PackedStages, by stage and name."""
+    out = {"out_cols": packed.out_cols, "n_cols0": np.asarray(packed.n_cols0)}
+    for k, st in enumerate(packed.stages):
+        for field in dataclasses.fields(st):
+            v = getattr(st, field.name)
+            if field.name == "epilogue":
+                for m, e in enumerate(v):
+                    out[f"{k}.epi{m}"] = np.asarray(e.params)
+                    out[f"{k}.epi{m}.op"] = np.asarray([e.op, e.mode])
+            elif v is not None:
+                out[f"{k}.{field.name}"] = np.asarray(v)
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, None], ids=["int32", "int64"])
+def test_pack_stages_of_mac_front_matches_enumerated_table(dtype, monkeypatch):
+    """The Pallas path packs a "mac" front exactly as it packed the
+    enumerated table the composer built for the same chains: the same
+    arrays, table lane dtypes included."""
+    from repro.core.analysis import analyze_ranges
+    from repro.kernels import lut_serve
+    from repro.kernels.lut_serve import mac_as_lut
+
+    prog = _hybrid_conv_prog()
+    ranges = analyze_ranges(prog)
+    mac, why = compose_fused_stages(prog, jnp.int32, ranges=ranges)
+    assert mac is not None, why
+    assert [st.kind for st in mac.stages] == ["mac", "lut", "lut", "sum"]
+    monkeypatch.setattr(lut_serve, "_mac_fields", lambda *a: None)
+    enum, why = compose_fused_stages(prog, jnp.int32, ranges=ranges)
+    assert enum is not None, why
+    assert enum.stages[0].kind == "lut"
+
+    as_lut = mac_as_lut(mac.stages[0])
+    for name in ("in_shift", "mask", "table", "out_shift", "live", "bias"):
+        np.testing.assert_array_equal(getattr(as_lut, name),
+                                      getattr(enum.stages[0], name))
+    got, want = (_packed_arrays(pack_stages(st, dtype)) for st in (mac, enum))
+    assert got.keys() == want.keys()
+    for name in want:
+        assert got[name].dtype == want[name].dtype, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
 def test_residency_budget_is_a_pack_error():
     layer = LUTDense(4, 3, hidden=4)
     prog = compile_sequential([layer], [layer.init(KEY)], IN_F, IN_I)
@@ -193,8 +244,8 @@ def test_pack_failure_falls_back_to_fused_with_warning(monkeypatch):
 
 
 def test_unfusable_program_degrades_to_generic_with_warning():
-    h1 = HGQDense(3, 2)         # operands too wide to enumerate
-    prog = compile_sequential([h1], [h1.init(KEY)], input_f=18, input_i=6)
+    # a non-linear chain on an operand too wide to enumerate
+    prog = nonlinear_prog(width=24)
     with pytest.warns(EnginePathWarning, match="pallas"):
         engine = compile_program(prog, engine="pallas")
     assert engine.path == "generic" and not engine.fused
@@ -279,7 +330,7 @@ def test_artifact_v3_round_trips_packed_payload(tmp_path):
     path = str(tmp_path / "m.npz")
     save_artifact(path, prog)
     art = load_artifact(path)
-    assert art.meta["format_version"] == 3 and art.meta["packed"]
+    assert art.meta["format_version"] == 4 and art.meta["packed"]
     assert art.packed is not None
     # the stored payload is the lane-packed layout, not a re-derivation
     assert {str(st.table.dtype) for st in art.packed.stages

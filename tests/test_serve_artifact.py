@@ -188,7 +188,7 @@ def test_conv_hybrid_bundle_round_trip_v2(tmp_path):
     save_artifact(path, prog, attestation=gate)
 
     art = load_artifact(path)
-    assert art.meta["format_version"] == 3
+    assert art.meta["format_version"] == 4
     assert art.stages is not None and art.stages.n_stages() == 4
     loaded = _engine(art)
     assert loaded.path == "fused"
@@ -198,6 +198,82 @@ def test_conv_hybrid_bundle_round_trip_v2(tmp_path):
     ref = prog.run(codes)
     np.testing.assert_array_equal(
         np.asarray(jax.device_get(loaded.run(codes)), np.int64), ref)
+
+
+def _same_packed(a, b):
+    assert a.n_stages() == b.n_stages()
+    for sa, sb in zip(a.stages, b.stages):
+        assert sa.kind == sb.kind
+        for name in ("gather", "bias", "in_shift", "mask", "table", "coef"):
+            va, vb = getattr(sa, name), getattr(sb, name)
+            assert (va is None) == (vb is None), name
+            if va is not None:
+                assert np.asarray(va).dtype == np.asarray(vb).dtype, name
+                np.testing.assert_array_equal(va, vb, err_msg=name)
+
+
+def test_mac_stage_bundle_v4_round_trips(tmp_path):
+    """A v4 bundle stores the hybrid's "mac" front (requants, formats,
+    folded weights) and its packed enumerated form; both engines built
+    from it are bit-exact."""
+    from repro.core.analysis import analyze_ranges
+    from repro.kernels.lut_serve import compose_fused_stages
+    from repro.kernels.lut_serve_pallas import pack_stages
+
+    prog = _hybrid_conv_prog()
+    fresh = compile_program(prog)
+    assert fresh.stage_kinds == ("mac", "lut", "lut", "sum")
+    path = str(tmp_path / "mac.npz")
+    save_artifact(path, prog)
+    art = load_artifact(path)
+    assert art.meta["format_version"] == 4 and art.meta["packed"]
+    st = art.stages.stages[0]
+    assert st.kind == "mac" and st.requant_mode == "SAT"
+    assert st.table is None and st.weight.shape == (4, 3)
+    stages, _ = compose_fused_stages(prog, ranges=analyze_ranges(prog))
+    _same_packed(art.packed, pack_stages(stages))
+
+    lo, hi = input_code_bounds(prog)
+    codes = np.concatenate([
+        np.random.default_rng(3).integers(lo, hi + 1, (256, len(lo))),
+        np.stack([lo, hi])])
+    ref = prog.run(codes)
+    for engine in ("fused", "pallas"):
+        loaded = _engine(art, engine=engine)
+        assert loaded.path == engine and loaded.fuse_reason == ""
+        np.testing.assert_array_equal(
+            np.asarray(jax.device_get(loaded.run(codes)), np.int64), ref)
+
+
+def test_v3_bundle_still_loads(tmp_path, monkeypatch):
+    """A v3 bundle, written before the "mac" kind (its HGQ front an
+    enumerated "lut" stage), loads as it did and serves bit-exactly."""
+    from repro.kernels import lut_serve
+    from repro.serve.artifact import _bundle_digest
+
+    prog = _hybrid_conv_prog()
+    path = str(tmp_path / "v3.npz")
+    with monkeypatch.context() as m:
+        m.setattr(lut_serve, "_mac_fields", lambda *a: None)
+        save_artifact(path, prog)
+
+    def as_v3(arrays):
+        meta = json.loads(bytes(arrays.pop("meta_json")).decode())
+        meta_core = {k: v for k, v in meta.items() if k != "content_hash"}
+        meta_core["format_version"] = 3
+        digest = _bundle_digest(arrays, meta_core)
+        arrays["meta_json"] = np.frombuffer(json.dumps(
+            {**meta_core, "content_hash": digest}, sort_keys=True).encode(),
+            np.uint8)
+    _rewrite(path, as_v3)
+
+    art = load_artifact(path)
+    assert art.meta["format_version"] == 3 and art.packed is not None
+    assert [st.kind for st in art.stages.stages] == ["lut", "lut", "lut", "sum"]
+    for engine in ("fused", "pallas"):
+        loaded = _engine(art, engine=engine)
+        assert loaded.path == engine
+        verify_engine(loaded, prog, n_random=128)
 
 
 def test_v1_bundle_negotiated(tmp_path):
@@ -404,7 +480,7 @@ def test_pre_rtl_bundles_still_load(tmp_path):
     path = str(tmp_path / "pre_rtl.npz")
     save_artifact(path, prog, attestation={"random": 32, "exhaustive": 0})
     art = load_artifact(path)
-    assert art.meta["format_version"] == 3
+    assert art.meta["format_version"] == 4
     assert "rtl" not in art.attestation
     verify_engine(_engine(art), art.prog, n_random=128)
 
