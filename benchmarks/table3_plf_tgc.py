@@ -2,7 +2,8 @@
 
 * PLF: JEDI-Linear-style permutation-invariant network with the paper's
   substitution — EinsumDense → LUT-Dense (per-particle encoder + sum pool +
-  LUT-Dense classifier head), hidden dim 8 as in §V-D.
+  LUT-Dense classifier head), hidden dim 8 as in §V-D: the canonical
+  ``repro.models.plf`` list, trained on the normal path.
 * TGC: hybrid per §V-E — HGQ (matmul) feature extractor + LUT-Dense output
   head, regression target in mrad; metric is angular resolution (RMS).
 """
@@ -16,58 +17,49 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro.core.ebops import estimate_luts
+from repro.core.ebops import BetaSchedule, estimate_luts
 from repro.core.hgq_layers import HGQDense
 from repro.core.lut_layers import LUTDense
 from repro.core.quant import int_to_float, quantize_to_int
 from repro.data.synthetic import jsc_plf, tgc_muon
+from repro.models.plf import IN_F, IN_I, build_plf_layers
 from repro.nn.base import merge_aux
 from repro.optim.adam import AdamConfig, adam_init, adam_update, cosine_restarts
+from repro.train.loop import run_chunked
+from repro.train.steps import TrainHParams, make_lut_train_step
 
 
-def run_plf() -> None:
-    N_P, N_F, HID = 16, 8, 8       # paper reduces hidden dims to 8
-    xtr, ytr = jsc_plf(0, 8000, N_P, N_F, "train")
-    xte, yte = jsc_plf(0, 2000, N_P, N_F, "test")
-    q = lambda x: int_to_float(quantize_to_int(x, 4, 3, True, "SAT"), 4)
+def run_plf(steps: int = 400, batch: int = 512, n_train: int = 8000,
+            n_test: int = 2000) -> None:
+    """The canonical model (``repro.models.plf``) at the generator's 32
+    particles of 16 features, trained through ``make_lut_train_step``
+    under ``train/loop.chunked_train``."""
+    xtr, ytr = jsc_plf(0, n_train, split="train")
+    xte, yte = jsc_plf(0, n_test, split="test")
+    q = lambda x: int_to_float(quantize_to_int(x, IN_F, IN_I, True, "SAT"),
+                               IN_F).astype(np.float32)
     xtr, xte = q(xtr), q(xte)
 
-    enc = LUTDense(N_F, HID, hidden=8, use_batchnorm=True)   # per-particle
-    head = LUTDense(HID, 5, hidden=8)
-    ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    params = {"enc": enc.init(ks[0]), "head": head.init(ks[1])}
-    opt = adam_init(params)
-    acfg = AdamConfig(lr=3e-3)
-    sched = cosine_restarts(3e-3, first_period=200, warmup=20)
+    layers = build_plf_layers()        # encoder, set sum, head; hidden 8
+    hp = TrainHParams(adam=AdamConfig(lr=3e-3),
+                      beta=BetaSchedule(1e-7, None),
+                      lr_schedule=cosine_restarts(3e-3, first_period=200,
+                                                  warmup=20))
+    step, init = make_lut_train_step(layers, hp, jit=False)
+    params, opt = init(jax.random.PRNGKey(0))
+    idx = np.random.default_rng(0).integers(0, len(xtr), (steps, batch))
+    get = lambda s: {"x": xtr[idx[s]], "y": ytr[idx[s]]}
 
-    def fwd(p, x, train):
-        h, a1 = enc.apply(p["enc"], x, train=train)       # (B, P, HID)
-        pooled = jnp.mean(h, axis=1)                      # permutation-inv
-        logits, a2 = head.apply(p["head"], pooled, train=train)
-        return logits, merge_aux(a1, a2)
-
-    @jax.jit
-    def step(params, opt, x, y):
-        def loss_fn(p):
-            logits, aux = fwd(p, x, True)
-            ce = -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(x.shape[0]), y])
-            return ce + 1e-7 * aux.ebops, aux
-        (_, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        params, opt, _ = adam_update(params, g, opt, acfg, sched)
-        for path, val in aux.updates.items():
-            params["enc"][path] = val
-        return params, opt, aux.ebops
-
-    rng = np.random.default_rng(0)
     t0 = time.time()
-    for s in range(400):
-        idx = rng.integers(0, len(xtr), 512)
-        params, opt, ebops = step(params, opt, jnp.asarray(xtr[idx]),
-                                  jnp.asarray(ytr[idx]))
-    us = (time.time() - t0) / 400 * 1e6
-    logits, aux = fwd(params, jnp.asarray(xte), False)
-    acc = float(jnp.mean(jnp.argmax(logits, -1) == jnp.asarray(yte)))
-    eb = float(aux.ebops)
+    params, opt, _ = run_chunked(step, params, opt, get, 0, steps,
+                                 chunk_steps=50)
+    us = (time.time() - t0) / steps * 1e6
+    h, auxes = jnp.asarray(xte), []
+    for k, layer in enumerate(layers):
+        h, a = layer.apply(params[f"l{k}"], h)
+        auxes.append(a)
+    acc = float(jnp.mean(jnp.argmax(h, -1) == jnp.asarray(yte)))
+    eb = float(merge_aux(*auxes).ebops)
     emit("table3/plf_lut_gnn", us,
          f"acc={acc:.4f};ebops={eb:.0f};est_luts={estimate_luts(eb):.0f}")
 

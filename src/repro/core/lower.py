@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dais import DaisProgram, Reg, Segment, _tree_add
@@ -41,6 +42,7 @@ from repro.core.hgq_layers import HGQConv1D, HGQDense
 from repro.core.lut_layers import LUTConv1D, LUTConv2D, LUTDense, _same_pads
 from repro.core.quant import int_bits, quantize_to_int
 from repro.core.tables import extract_tables
+from repro.nn.base import Aux
 
 
 # --------------------------------------------------------------------------- #
@@ -68,7 +70,21 @@ class ReLU:
 
 @dataclasses.dataclass(frozen=True)
 class WindowSum:
-    """Per-channel sum over every spatial site (window-count accumulation)."""
+    """Per-channel sum over every spatial site (window-count accumulation,
+    set pooling).
+
+    Also a trainable node with no parameters: ``apply`` sums a
+    ``(B, *sites, C)`` activation over its site axes, so one node list both
+    trains (``make_lut_train_step``) and lowers.
+    """
+
+    def init(self, key) -> dict:
+        return {}
+
+    def apply(self, params: dict, x, *, train: bool = False):
+        if x.ndim < 3:
+            raise ValueError(f"WindowSum needs (B, sites..., C), got {x.shape}")
+        return jnp.sum(x, axis=tuple(range(1, x.ndim - 1))), Aux.zero()
 
 
 @dataclasses.dataclass

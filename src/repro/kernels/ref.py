@@ -74,10 +74,122 @@ def lut_dense_train_ref(
     """
     from repro.core.quant import fq_surrogate
 
+    y = lut_cells_train_ref(x, w0, b0, w_out, b_out, f_in, i_in)
+    yq = fq_surrogate(y, f_out, i_out, signed=True, overflow="SAT")
+    return jnp.sum(yq, axis=1).astype(x.dtype)
+
+
+def lut_cells_train_ref(x: Array, w0: Array, b0: Array, w_out: Array,
+                        b_out: Array, f_in: Array, i_in: Array) -> Array:
+    """The first half of :func:`lut_dense_train_ref`: every cell's value
+    before its output quantizer, (B, C_in, C_out).  A caller with
+    batch-norm normalizes these before quantizing them."""
+    from repro.core.quant import fq_surrogate
+
     xb = jnp.broadcast_to(x[:, :, None].astype(jnp.float32),
                           x.shape + (w0.shape[-1],))
     xq = fq_surrogate(xb, f_in, i_in, signed=True, overflow="WRAP")
     h = jnp.tanh(xq[:, :, None, :] * w0[None] + b0[None])
-    y = jnp.sum(h * w_out[None], axis=2) + b_out[None]
-    yq = fq_surrogate(y, f_out, i_out, signed=True, overflow="SAT")
-    return jnp.sum(yq, axis=1).astype(x.dtype)
+    return jnp.sum(h * w_out[None], axis=2) + b_out[None]
+
+
+# --------------------------------------------------------------------------- #
+# JSC-PLF (models/plf.py): per-particle encoder, set sum, head
+# --------------------------------------------------------------------------- #
+# bit-width clip range of the layers' quantizers (QuantConfig defaults)
+BIT_MIN, BIT_MAX = -8.0, 12.0
+BN_EPS, BN_MOMENTUM = 1e-5, 0.99
+
+
+def _kernel_layout(p: dict):
+    """A LUT-Dense parameter dict's MLP tensors in the (C_in, H, C_out)
+    layout of :func:`lut_dense_ref`."""
+    return (jnp.transpose(p["w0"], (0, 2, 1)), jnp.transpose(p["b0"], (0, 2, 1)),
+            jnp.transpose(p["w_out"], (0, 2, 1)), p["b_out"])
+
+
+def _bits(q: dict, train: bool):
+    """Clipped, rounded (f, i); with a straight-through gradient in training."""
+    def r(v):
+        c = jnp.clip(v, BIT_MIN, BIT_MAX)
+        return c + jax.lax.stop_gradient(jnp.round(c) - c) if train \
+            else jnp.round(c)
+    return r(q["f"]), r(q["i"])
+
+
+def _ebops(f_in, i_in, f_out, i_out) -> Array:
+    """Eq. 5 of the paper (LUT-6 split into LUT-5s) over a cell grid."""
+    m = jnp.maximum(f_in + i_in + 1.0, 0.0)
+    n = jnp.maximum(f_out + i_out + 1.0, 0.0)
+    cost = jnp.where(m >= 5, jnp.exp2(m - 6) * n, (m / 5) * 2.0 ** -1 * n)
+    return jnp.sum(jnp.where((m > 0) & (n > 0), cost, 0.0))
+
+
+def plf_ref(params, x: Array) -> Array:
+    """Eval-mode JSC-PLF forward: (B, P, F) inputs on the input grid ->
+    (B, n_classes) logits on the head's output grid.
+
+    ``params`` is ``[encoder, pool, head]`` (the pool's entry is ignored),
+    each LUT-Dense dict as ``LUTDense.init`` lays it out.  The encoder runs
+    :func:`lut_dense_ref` on every particle with its batch-norm (moving
+    statistics) folded into the output projection, the pool is a
+    ``jnp.sum`` over particles, the head is :func:`lut_dense_ref` again.
+    float32 at the highest matmul precision; no kernels.
+
+    Departure from the paper: the pool is a sum, where the JEDI-linear
+    network it follows takes a mean; the two differ by the constant 1/P,
+    which the head's input quantizer absorbs.
+    """
+    enc, _pool, head = params
+    b, n_p, n_f = x.shape
+    with jax.default_matmul_precision("highest"):
+        w0, b0, wo, bo = _kernel_layout(enc)
+        inv = enc["bn_scale"] * jax.lax.rsqrt(enc["bn_var"] + BN_EPS)
+        wo = wo * inv[:, None, :]
+        bo = bo * inv + enc["bn_bias"] - enc["bn_mean"] * inv
+        h = lut_dense_ref(x.reshape(b * n_p, n_f).astype(jnp.float32),
+                          w0, b0, wo, bo, *_bits(enc["q_in"], False),
+                          *_bits(enc["q_out"], False))
+        s = jnp.sum(h.reshape(b, n_p, -1), axis=1)
+        return lut_dense_ref(s, *_kernel_layout(head), *_bits(head["q_in"], False),
+                             *_bits(head["q_out"], False))
+
+
+def plf_train_ref(params, x: Array, y: Array, beta: float):
+    """Train-mode JSC-PLF loss, differentiable in ``params``.
+
+    ``params`` is ``{"l0": encoder, "l1": {}, "l2": head}``, the layout of
+    ``make_lut_train_step``.  The encoder's cells (:func:`lut_cells_train_ref`)
+    run on every particle; its batch-norm takes its statistics over batch
+    and particles; the pool is a ``jnp.sum`` over particles and the head is
+    :func:`lut_dense_train_ref`.  The loss is softmax cross-entropy plus
+    ``beta`` times the EBOPs of both layers' cells (one table set a layer,
+    as the surrogate counts them).  Returns ``(loss, aux)``, ``aux`` holding
+    ``ce``, ``ebops`` and the encoder's new moving statistics
+    (``bn_mean``, ``bn_var``).  float32 at the highest matmul precision.
+    Departure from the paper: the sum pool (see :func:`plf_ref`).
+    """
+    from repro.core.quant import fq_surrogate
+
+    enc, head = params["l0"], params["l2"]
+    b, n_p, n_f = x.shape
+    with jax.default_matmul_precision("highest"):
+        fi, ii = _bits(enc["q_in"], True)
+        fo, io = _bits(enc["q_out"], True)
+        cells = lut_cells_train_ref(x.reshape(b * n_p, n_f).astype(jnp.float32),
+                                    *_kernel_layout(enc), fi, ii)
+        mean, var = jnp.mean(cells, axis=0), jnp.var(cells, axis=0)
+        cells = ((cells - mean) * jax.lax.rsqrt(var + BN_EPS) * enc["bn_scale"]
+                 + enc["bn_bias"])
+        h = jnp.sum(fq_surrogate(cells, fo, io, signed=True, overflow="SAT"),
+                    axis=1)
+        s = jnp.sum(h.reshape(b, n_p, -1), axis=1)
+        hb = (*_bits(head["q_in"], True), *_bits(head["q_out"], True))
+        logits = lut_dense_train_ref(s, *_kernel_layout(head), *hb)
+        ce = -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(b), y])
+        ebops = _ebops(fi, ii, fo, io) + _ebops(*hb)
+        stats = {"bn_mean": BN_MOMENTUM * enc["bn_mean"]
+                 + (1 - BN_MOMENTUM) * jax.lax.stop_gradient(mean),
+                 "bn_var": BN_MOMENTUM * enc["bn_var"]
+                 + (1 - BN_MOMENTUM) * jax.lax.stop_gradient(var)}
+        return ce + beta * ebops, {"ce": ce, "ebops": ebops, **stats}

@@ -29,6 +29,7 @@ Usage:
     PYTHONPATH=src python -m repro.launch.lint /tmp/model.npz
     PYTHONPATH=src python -m repro.launch.lint --model lut-stack \
         --lut-dims 16,20,5
+    PYTHONPATH=src python -m repro.launch.lint --model jsc-plf
     PYTHONPATH=src python -m repro.launch.lint --model pid-hybrid --ctx 100 \
         --all-regs
 """
@@ -179,8 +180,9 @@ def main(argv=None) -> None:
                     "self-certified DCE")
     ap.add_argument("bundles", nargs="*",
                     help="compiled-artifact bundle paths (.npz)")
-    ap.add_argument("--model", choices=("lut-stack", "pid-hybrid"),
-                    default=None,
+    from repro.launch.serve import MODELS
+
+    ap.add_argument("--model", choices=MODELS, default=None,
                     help="lint a freshly built model program instead of "
                          "(or in addition to) bundles")
     ap.add_argument("--lut-dims", default="16,20,5")

@@ -42,6 +42,10 @@ throughput against the numpy-interpreter baseline.
 conv PID architecture (``repro.models.pid``), lowered through the graph
 frontend (``core.lower``) so its conv layers share one table set across
 all spatial sites and the engine runs on the fused shared-table path.
+``--model jsc-plf`` serves the particle-list jet tagger
+(``repro.models.plf``) the same way: its per-particle encoder shares one
+table set across the 32 particle sites, then a set sum and a LUT head
+(``stages=lut,sum,lut``).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --arch qwen15_05b --smoke \
@@ -50,6 +54,8 @@ Usage:
         --lut-dims 16,20,5 --batch 1024 --gen 8
     PYTHONPATH=src python -m repro.launch.serve --engine tables \
         --model pid-hybrid --ctx 100 --batch 1024
+    PYTHONPATH=src python -m repro.launch.serve --engine tables \
+        --model jsc-plf --batch 1024
     PYTHONPATH=src python -m repro.launch.serve --engine tables \
         --artifact /tmp/model.npz --skip-verify-cached --serve-loop \
         --rate 2000 --requests 2048
@@ -64,6 +70,9 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# the model specs --engine tables (and launch/lint.py) can build
+MODELS = ("lut-stack", "pid-hybrid", "jsc-plf")
 
 
 def main(argv=None) -> None:
@@ -83,12 +92,14 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     # --engine tables model spec (untrained init is fine: serving exactness
     # is a property of the compiled tables, not of the weights' quality)
-    ap.add_argument("--model", choices=("lut-stack", "pid-hybrid"),
+    ap.add_argument("--model", choices=MODELS,
                     default="lut-stack",
                     help="lut-stack: LUT-Dense chain from --lut-dims; "
                          "pid-hybrid: the paper's hybrid conv PID model "
                          "(HGQ conv -> LUT convs -> LUT head -> window sum) "
-                         "compiled through the graph frontend")
+                         "compiled through the graph frontend; jsc-plf: the "
+                         "paper's particle-list jet tagger (per-particle "
+                         "LUT encoder -> set sum -> LUT head)")
     ap.add_argument("--ctx", type=int, default=100,
                     help="pid-hybrid waveform context length in samples "
                          "(multiple of the 20-sample DAQ window)")
@@ -248,6 +259,15 @@ def _build_model_program(args):
         graph = build_pid_graph(layers, n_samples=args.ctx)
         prog = lower(graph, [*params, None])
         return prog, f"model=pid-hybrid ctx={args.ctx}"
+    if args.model == "jsc-plf":
+        from repro.core.lower import lower
+        from repro.models.plf import (build_plf_graph, build_plf_layers,
+                                      init_plf_params)
+
+        layers = build_plf_layers(hidden=args.lut_hidden)
+        params = init_plf_params(layers, jax.random.PRNGKey(args.seed))
+        prog = lower(build_plf_graph(layers), params)
+        return prog, "model=jsc-plf particles=32"
 
     from repro.core.dais import compile_sequential
     from repro.core.lut_layers import LUTDense

@@ -125,7 +125,13 @@ def make_lut_train_step(layers, hp: TrainHParams = TrainHParams(),
     """CE + β·EBOPs train step over a stack of LUT layers (the paper-task
     counterpart of :func:`make_train_step`).
 
-    With ``hp.lut_use_fused`` every layer is rerouted through the fused
+    ``layers`` may hold parameter-free structural nodes such as
+    ``core.lower.WindowSum`` (the JSC-PLF set sum, ``models/plf.py``):
+    their ``init`` returns ``{}`` and ``apply`` gives no EBOPs.  ``batch["x"]``
+    is ``(B, C)``, or ``(B, *sites, C)`` for a model whose first layers run
+    time-distributed over sites.
+
+    With ``hp.lut_use_fused`` every LUT layer is rerouted through the fused
     Pallas forward + recompute backward (kernels/lut_dense*.py), so one
     training step runs entirely kernel-side.  Returns ``(step_fn, init_fn)``;
     ``step_fn(params, opt_state, batch)`` with ``batch = {"x", "y"}``.
@@ -136,7 +142,9 @@ def make_lut_train_step(layers, hp: TrainHParams = TrainHParams(),
     from repro.nn.base import merge_aux, scoped_updates
 
     if hp.lut_use_fused:
-        layers = [dataclasses.replace(l, use_fused=True) for l in layers]
+        # structural nodes (``WindowSum``) have no parameters and no kernel
+        layers = [dataclasses.replace(l, use_fused=True)
+                  if hasattr(l, "use_fused") else l for l in layers]
 
     def step_fn(params, opt_state, batch):
         step = opt_state["step"]

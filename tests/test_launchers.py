@@ -110,6 +110,23 @@ def test_serve_launcher_pid_hybrid():
 
 
 @pytest.mark.slow
+def test_serve_launcher_jsc_plf():
+    """--model jsc-plf: the particle-list tagger lowers through the graph
+    frontend and serves on the fused path as encoder, set sum and head;
+    gate passes."""
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--engine", "tables",
+         "--model", "jsc-plf", "--smoke",
+         "--batch", "32", "--gen", "1"],
+        env=ENV, cwd=REPO, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "model=jsc-plf particles=32" in r.stdout
+    assert "path=fused" in r.stdout
+    assert "stages=lut,sum,lut" in r.stdout
+    assert "bit-exact gate PASSED" in r.stdout
+
+
+@pytest.mark.slow
 def test_train_launcher_smoke():
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "rwkv6_16b",

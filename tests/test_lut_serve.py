@@ -294,10 +294,12 @@ def test_non_mac_chains_stay_enumerated(build):
     assert stats["exhaustive"] == 1 << 16
 
 
-@pytest.mark.parametrize("model", ["pid", "jsc"])
+@pytest.mark.parametrize("model", ["pid", "jsc", "plf"])
 def test_default_engine_stage_kinds(model):
     """With the default EngineSpec the PID hybrid runs its HGQ front as
-    "mac" and the rest as before; a LUT-Dense stack runs no "mac" stage."""
+    "mac" and the rest as before; a LUT-Dense stack runs no "mac" stage;
+    JSC-PLF runs its encoder, its set sum (a window sum in the middle of
+    the chain) and its head as "lut", "sum", "lut"."""
     from repro.serve.api import EngineSpec, build
 
     if model == "pid":
@@ -309,6 +311,14 @@ def test_default_engine_stage_kinds(model):
         prog = lower(build_pid_graph(layers, n_samples=100),
                      [*params, None])
         want = ("mac", "lut", "lut", "lut", "sum")
+    elif model == "plf":
+        from repro.core.lower import lower
+        from repro.models.plf import (build_plf_graph, build_plf_layers,
+                                      init_plf_params)
+        layers = build_plf_layers(n_features=4)
+        params = init_plf_params(layers, jax.random.PRNGKey(0))
+        prog = lower(build_plf_graph(layers, n_particles=8), params)
+        want = ("lut", "sum", "lut")
     else:
         layers = [LUTDense(16, 20, hidden=8, use_batchnorm=True),
                   LUTDense(20, 5, hidden=8)]
