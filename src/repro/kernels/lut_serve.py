@@ -19,8 +19,10 @@ a fallback to the generic path logs its reason and records it on
    :class:`FusedStage`.  The layer's tables are composed **once** and
    shared by all spatial sites — a "lut" layer keeps its
    :class:`~repro.core.tables.LayerTables` and runs as per-site gather →
-   requant → batched table gather → Σ; an "hgq" layer whose chains are
-   one REQUANT per input then constant multiplies runs as gather →
+   requant → one-hot × table contraction on the MXU (a batched table
+   gather → Σ where the one-hot would be too wide; ``stage_forms``); an
+   "hgq" layer whose chains are one REQUANT per input then constant
+   multiplies runs as gather →
    requant → integer multiply-accumulate (kind "mac"), and any other
    "hgq" chain is enumerated over all input codes into an equivalent
    table (relu folds into a vectorized epilogue); window sums and
@@ -229,6 +231,8 @@ class ServeEngine:
     packed_table_bytes: int = 0  # lane-packed table bytes ("pallas" only)
     stage_kinds: Tuple[str, ...] = ()   # kind of each stage it runs
                                         # (fused/pallas); () on "generic"
+    stage_forms: Tuple[str, ...] = ()   # how each runs: "onehot"/"gather"
+                                        # for a fused "lut" stage, else kind
     # counters of this handle (not locked: one handle per thread, clone())
     n_calls: int = 0            # run() calls
     place_s: float = 0.0        # Σ seconds of cast + host->device + shard
@@ -371,7 +375,7 @@ def compile_program(prog: DaisProgram, *, mesh=None,
     input_widths = np.asarray([ins.reg.width for ins in in_instrs], np.int64)
 
     run, n_groups, path = None, 0, "generic"
-    n_launches, packed_bytes, kinds = 0, 0, ()
+    n_launches, packed_bytes, kinds, forms = 0, 0, (), ()
     downgrades: List[str] = []
     reason = ""
     if want in ("pallas", "fused") and stages is None:
@@ -388,7 +392,7 @@ def compile_program(prog: DaisProgram, *, mesh=None,
                                             block_batch=block_batch)
                 path, n_groups = "pallas", packed.n_stages()
                 n_launches, packed_bytes = 1, packed.table_bytes()
-                kinds = tuple(st.kind for st in packed.stages)
+                kinds = forms = tuple(st.kind for st in packed.stages)
             except _pallas.PackError as e:
                 downgrades.append(f"pallas unavailable: {e}")
     if run is None and want in ("pallas", "fused"):
@@ -396,6 +400,7 @@ def compile_program(prog: DaisProgram, *, mesh=None,
             run, path = _fused_runner(stages, dtype, mesh), "fused"
             n_groups = n_launches = stages.n_stages()
             kinds = tuple(st.kind for st in stages.stages)
+            forms = tuple(stage_form(st) for st in stages.stages)
         elif want == "fused":
             downgrades.append(f"fused unavailable: {reason}")
     if run is None:
@@ -419,7 +424,7 @@ def compile_program(prog: DaisProgram, *, mesh=None,
         input_widths=input_widths, output_f=list(prog.output_f),
         mesh=mesh, _runner=jax.jit(run) if jit else run,
         n_launches=n_launches, packed_table_bytes=packed_bytes,
-        stage_kinds=kinds)
+        stage_kinds=kinds, stage_forms=forms)
 
 
 def _group_runner(prog: DaisProgram, dtype, mesh):
@@ -1198,8 +1203,131 @@ def compose_fused_stages(prog: DaisProgram, dtype: Optional[object] = None,
 
 
 # ------------------------------------------------------------------ runner
-def _prepare_stage(stage: FusedStage, dtype):
-    """Close one FusedStage over device constants -> (B, n_cols) -> (B, S*co)."""
+# A "lut" stage runs as a one-hot x table contraction (form "onehot") unless
+# its one-hot width K exceeds this multiple of the J * co cells it replaces:
+# then the per-cell gather (form "gather") stays.  Below the break-even of
+# 1600-5400 measured on a TPU v5e (docs/kernels.md).
+_ONEHOT_MAX_RATIO = 1024
+# One-hot bytes of one row block of the contraction, a device's share.
+_ONEHOT_BLOCK_BYTES = 1 << 30
+
+
+def _lut_classes(stage: FusedStage) -> List[Tuple[int, int, int]]:
+    """``(j, in_shift, width)`` of each class of a "lut" stage.
+
+    A class is one input position ``j`` read at one grid shift; every cell
+    of it indexes its table with the same shifted code, and ``width`` is the
+    power of two that covers the widest of their masks.
+    """
+    classes = []
+    for j, (shifts, masks) in enumerate(zip(stage.in_shift, stage.mask)):
+        for s in np.unique(shifts):
+            widest = int(masks[shifts == s].max())
+            classes.append((j, int(s), 1 << widest.bit_length()))
+    return classes
+
+
+def stage_form(stage: FusedStage) -> str:
+    """How the fused runner evaluates ``stage``: ``"onehot"`` or ``"gather"``
+    for a "lut" stage (:data:`_ONEHOT_MAX_RATIO`), else its kind."""
+    if stage.kind != "lut":
+        return stage.kind
+    j_n, co = stage.mask.shape
+    k = sum(w for _j, _s, w in _lut_classes(stage))
+    return "onehot" if 0 < k <= _ONEHOT_MAX_RATIO * j_n * co else "gather"
+
+
+def _split_limbs(t: np.ndarray) -> Tuple[np.ndarray, int]:
+    """``(K, co)`` int64 -> ``(K, L*co)`` int8 limbs with
+    ``t == Σ_l limb_l << 7l``; every limb but the last in [-64, 63]."""
+    limbs = []
+    while t.min() < -128 or t.max() > 127:
+        d = ((t + 64) & 127) - 64
+        limbs.append(d)
+        t = (t - d) >> 7
+    limbs.append(t)
+    return np.concatenate(limbs, axis=1).astype(np.int8), len(limbs)
+
+
+def _onehot_stage(stage: FusedStage, dtype, mesh):
+    """The "onehot" form of a "lut" stage: (B, S, J) codes -> (B, S, co).
+
+    Per class ``p = (j, s)`` of width ``2**k``, ``u_p = shift_round(g_j, s)
+    & (2**k - 1)``; since every cell mask of the class is below ``2**k``,
+    ``u_p & mask`` is the cell's own index, so
+    ``T[p, u, i] = table[j, i, u & mask] << out_shift`` (0 for cells of
+    other classes) reproduces every lookup.  The stage is then
+    ``one_hot(u) @ T`` over ``K = Σ_p 2**k`` columns, an int8 dot on the
+    MXU: ``T`` is split into int8 limbs and recombined in ``dtype``, which
+    wraps exactly as the gather's sum.  Row blocks bound the one-hot a
+    device holds to :data:`_ONEHOT_BLOCK_BYTES`.
+    """
+    classes = _lut_classes(stage)
+    co = stage.c_out
+    # wraps to the engine dtype as the gather's ``table << out_shift`` does
+    vals = ((np.asarray(stage.table, np.int64)
+             << np.asarray(stage.out_shift, np.int64)[..., None])
+            .astype(np.dtype(dtype)).astype(np.int64))      # (J, co, E)
+    cells = np.arange(co)
+    widths = sorted({w for _j, _s, w in classes})
+    groups, rows = [], []
+    for w in widths:
+        members = [(j, s) for j, s, cw in classes if cw == w]
+        u = np.arange(w)[:, None]
+        for j, s in members:
+            t = vals[j, cells, u & stage.mask[j]]           # (w, co)
+            rows.append(np.where(stage.in_shift[j] == s, t, 0))
+        groups.append((np.asarray([j for j, _s in members], np.int32),
+                       jnp.asarray([s for _j, s in members], dtype), w))
+    limbs, n_limbs = _split_limbs(np.concatenate(rows))
+    k = limbs.shape[0]
+    parts = np.split(limbs, np.cumsum([len(c) * w for c, _s, w in groups]))
+    groups = [(cols, shifts, w, jnp.asarray(t.reshape(len(cols), w, -1)))
+              for (cols, shifts, w), t in zip(groups, parts)]
+    n_dev = 1 if mesh is None else mesh.devices.size
+
+    def contract(g):                                        # (b, S, J)
+        acc = 0
+        for cols, shifts, w, t in groups:
+            u = _shift_round(g[..., cols], shifts) & (w - 1)
+            hot = (u[..., None] == jnp.arange(w, dtype=dtype))
+            acc = acc + jnp.einsum("...pw,pwc->...c", hot.astype(jnp.int8),
+                                   t, preferred_element_type=jnp.int32)
+        acc = acc.astype(dtype).reshape(*g.shape[:-1], n_limbs, co)
+        out = acc[..., 0, :]
+        for l in range(1, n_limbs):
+            out = out + (acc[..., l, :] << (7 * l))
+        return out
+
+    def body(g):                                            # (B, S, J)
+        b = g.shape[0]
+        nb = _row_blocks(b, g.shape[1] * k, n_dev)
+        if nb == 1:
+            return contract(g)
+        # block n holds batch rows n, n + nb, ...: the batch's own sharding
+        # stays on the block's rows and the loop runs over unsharded blocks
+        blocks = jnp.moveaxis(g.reshape(b // nb, nb, *g.shape[1:]), 1, 0)
+        out = jax.lax.map(contract, blocks)
+        return jnp.moveaxis(out, 0, 1).reshape(b, *out.shape[2:])
+    return body
+
+
+def _row_blocks(b: int, row_bytes: int, n_dev: int) -> int:
+    """Fewest blocks, dividing each device's share of ``b`` rows, whose
+    one-hot of ``row_bytes`` a row fits :data:`_ONEHOT_BLOCK_BYTES`."""
+    local = b // n_dev if b % n_dev == 0 else b
+    for nb in range(1, local + 1):
+        if local % nb == 0 and local // nb * row_bytes <= _ONEHOT_BLOCK_BYTES:
+            return nb
+    return local
+
+
+def _prepare_stage(stage: FusedStage, dtype, mesh=None,
+                   form: Optional[str] = None):
+    """Close one FusedStage over device constants -> (B, n_cols) -> (B, S*co).
+
+    ``form`` overrides :func:`stage_form` for a "lut" stage.
+    """
     gather = jnp.asarray(np.asarray(stage.gather, np.int32))
     bias = jnp.asarray(stage.bias, dtype)[None]             # (1, S, co)
     epis = []
@@ -1214,7 +1342,9 @@ def _prepare_stage(stage: FusedStage, dtype):
             epis.append((e.op, "", jnp.asarray(e.params, dtype)[None],
                          None, None, None))
 
-    if stage.kind == "lut":
+    if (form or stage_form(stage)) == "onehot":
+        body = _onehot_stage(stage, dtype, mesh)
+    elif stage.kind == "lut":
         in_shift = jnp.asarray(stage.in_shift, dtype)       # (J, co)
         mask = jnp.asarray(stage.mask, dtype)
         table = jnp.asarray(stage.table, dtype)             # (J, co, E)
@@ -1268,10 +1398,14 @@ def _fused_runner(stages: FusedStages, dtype, mesh):
     """Close a :class:`FusedStages` over device constants -> runner fn.
 
     Each stage's ops carry the name scope ``stage<i>_<kind>`` in the
-    compiled program's metadata, so an op can be traced to its stage.
+    compiled program's metadata (``stage<i>_lut_onehot`` for a "lut" stage
+    in the "onehot" form), so an op can be traced to its stage.
     """
-    prepared = [(f"stage{i}_{st.kind}", _prepare_stage(st, dtype))
-                for i, st in enumerate(stages.stages)]
+    prepared = []
+    for i, st in enumerate(stages.stages):
+        form = stage_form(st)
+        scope = f"stage{i}_{st.kind}" + ("_onehot" if form == "onehot" else "")
+        prepared.append((scope, _prepare_stage(st, dtype, mesh, form)))
     out_cols = np.asarray(stages.out_cols, np.int64)
 
     def _run(x):

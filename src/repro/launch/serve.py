@@ -387,6 +387,7 @@ def _tables_engine(args, mesh):
     print(f"[serve] engine=tables {model_desc} instrs={prog.n_instrs()} "
           f"path={engine.path} groups={engine.n_groups} "
           f"stages={','.join(engine.stage_kinds) or '-'} "
+          f"forms={','.join(engine.stage_forms) or '-'} "
           f"dtype={np.dtype(engine.dtype).name} "
           f"mesh={tuple(mesh.devices.shape)}{pk}")
     print(f"[serve] bit-exact gate PASSED: {gate['random']} random + "

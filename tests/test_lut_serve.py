@@ -19,9 +19,11 @@ from repro.core.hgq_layers import HGQDense
 from repro.core.lut_layers import LUTDense
 from repro.core.quant import QuantConfig, quantize_to_int
 from repro.core.tables import extract_tables
-from repro.kernels.lut_serve import (EnginePathWarning, _requant_cols,
-                                     compile_program, input_code_bounds,
-                                     lower_tables, verify_engine)
+from repro.kernels.lut_serve import (EnginePathWarning, FusedStage,
+                                     _prepare_stage, _requant_cols,
+                                     compile_program, compose_fused_stages,
+                                     input_code_bounds, lower_tables,
+                                     verify_engine)
 
 from _hgq_progs import mixed_linear_prog, nonlinear_prog, per_cell_requant_prog
 
@@ -329,6 +331,194 @@ def test_default_engine_stage_kinds(model):
     engine = build(prog, EngineSpec(n_random=256)).engine
     assert engine.path == "fused"
     assert engine.stage_kinds == want
+    assert engine.stage_forms == tuple("onehot" if k == "lut" else k
+                                       for k in want)
+
+
+# --------------------------------------------------------------------------- #
+# the two forms of a "lut" stage: per-cell gather and one-hot contraction
+# --------------------------------------------------------------------------- #
+def _mixed_shift_prog(n_shifts, seed):
+    """One LUT-Dense layer whose cells read each input position on
+    ``n_shifts`` grids (up-shifts, down-shifts with ties, pruned cells), with
+    random table codes over each cell's whole output width."""
+    rng = np.random.default_rng(seed)
+    ci, co = 4, 6
+    layer = LUTDense(ci, co, hidden=4)
+    params = layer.init(jax.random.PRNGKey(seed))
+    f = np.empty((ci, co))
+    for j in range(ci):
+        grids = rng.choice(np.arange(-1, IN_F + 2), n_shifts, replace=False)
+        f[j] = rng.permutation(np.resize(grids, co))
+    params["q_in"] = {"f": jnp.asarray(f, jnp.float32),
+                      "i": jnp.asarray(rng.integers(0, 3, (ci, co)),
+                                       jnp.float32)}
+    prog = compile_sequential([layer], [params], IN_F, IN_I)
+    t = prog.tables[0]
+    half = np.left_shift(1, np.maximum(t.out_width, 1) - 1)[..., None]
+    codes = rng.integers(-half, half, t.codes.shape)
+    t.codes = np.where((t.in_width > 0)[..., None], codes, 0)
+    return prog
+
+
+def _forms(stage, dtype):
+    return {f: jax.jit(_prepare_stage(stage, dtype, form=f))
+            for f in ("gather", "onehot")}
+
+
+def _each_code_rows(lo, hi, rng):
+    """Every code of each position's width, the other positions random."""
+    rows = []
+    for j in range(len(lo)):
+        r = rng.integers(lo, hi + 1, (int(hi[j] - lo[j]) + 1, len(lo)))
+        r[:, j] = np.arange(lo[j], hi[j] + 1)
+        rows.append(r)
+    return np.concatenate(rows)
+
+
+@pytest.mark.parametrize("n_shifts", [1, 2, 3])
+def test_onehot_form_matches_gather_and_interpreter(n_shifts):
+    """The contraction equals the gather and ``DaisProgram.run`` code for
+    code: every input code of each position (negative codes, ties at each
+    down-shift), positions read on one, two or three grids, and codes far
+    outside the range the program declares."""
+    prog = _mixed_shift_prog(n_shifts, seed=n_shifts)
+    stages, _ = compose_fused_stages(prog)
+    (st,) = stages.stages
+    assert max(len(np.unique(r)) for r in st.in_shift) == n_shifts
+    assert (st.in_shift < 0).any() and (st.mask == 0).any()
+    engine = compile_program(prog)
+    assert engine.stage_forms == ("onehot",)
+
+    rng = np.random.default_rng(n_shifts)
+    lo, hi = input_code_bounds(prog)
+    codes = _each_code_rows(lo, hi, rng)
+    np.testing.assert_array_equal(
+        np.asarray(jax.device_get(engine.run(codes)), np.int64),
+        prog.run(codes))
+    forms = _forms(st, engine.dtype)
+    for x in (codes, rng.integers(16 * lo, 16 * hi + 1, (512, len(lo)))):
+        x = jnp.asarray(x, engine.dtype)
+        np.testing.assert_array_equal(forms["onehot"](x), forms["gather"](x))
+
+
+def _random_lut_stage(rng, entry_bits, max_out_shift, s=3, j_n=5, co=4,
+                      n_cols=7):
+    m = rng.integers(0, 7, (j_n, co))
+    shifts = rng.integers(-3, 2, (j_n, 3))
+    return FusedStage(
+        kind="lut", gather=rng.integers(0, n_cols + 1, (s, j_n)),
+        n_cols=n_cols, bias=rng.integers(-99, 100, (s, co)),
+        in_shift=np.take_along_axis(shifts, rng.integers(0, 3, (j_n, co)), 1),
+        mask=(np.int64(1) << m) - 1,
+        table=rng.integers(-(1 << entry_bits), 1 << entry_bits,
+                           (j_n, co, 1 << int(m.max()))),
+        out_shift=rng.integers(0, max_out_shift + 1, (j_n, co)))
+
+
+@pytest.mark.parametrize("entry_bits, max_out_shift, block_rows, wraps", [
+    (13, 0, None, False),       # two limbs
+    (13, 6, None, False),       # three limbs
+    (13, 20, None, True),       # entries past int32: the sum wraps
+    (13, 20, 4, True),          # the same over row blocks
+], ids=["limbs2", "limbs3", "wraps", "wraps_blocks"])
+def test_onehot_limbs_match_gather(entry_bits, max_out_shift, block_rows,
+                                   wraps, monkeypatch):
+    """Folded entries beyond int8 split into int8 limbs that recombine to
+    the gather's int32 sum, modulo 2**32 where that sum wraps; row blocks
+    (the bounded-memory loop) change nothing."""
+    from repro.kernels import lut_serve
+
+    rng = np.random.default_rng(entry_bits + max_out_shift)
+    st = _random_lut_stage(rng, entry_bits, max_out_shift)
+    k = sum(w for _j, _s, w in lut_serve._lut_classes(st))
+    if block_rows is not None:
+        monkeypatch.setattr(lut_serve, "_ONEHOT_BLOCK_BYTES",
+                            block_rows * st.n_sites * k)
+    folded = st.table << st.out_shift[..., None]
+    assert np.abs(folded).max() > 127
+    assert (np.abs(folded).max() >= 1 << 31) == wraps
+    forms = _forms(st, jnp.int32)
+    x = jnp.asarray(rng.integers(-1000, 1000, (12, st.n_cols)), jnp.int32)
+    np.testing.assert_array_equal(forms["onehot"](x), forms["gather"](x))
+
+
+def _wide_cell_prog():
+    """One 12-bit-input cell: 4096 table entries, K far past the cap."""
+    layer = LUTDense(1, 1, hidden=4)
+    params = layer.init(KEY)
+    params["q_in"] = {"f": jnp.full((1, 1), 8.0), "i": jnp.full((1, 1), 3.0)}
+    return compile_sequential([layer], [params], 8, 3)
+
+
+def _bench_config_prog(name, monkeypatch):
+    """A benchmark configuration's program, at the widths it is served
+    (PID over a shorter waveform: the stage shapes do not depend on it)."""
+    import importlib.util
+    import json
+    import os
+
+    here = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip")
+    monkeypatch.syspath_prepend(here)
+    with open(os.path.join(here, "configs", f"{name}.json")) as f:
+        cfg = json.load(f)
+    if "n_samples" in cfg:
+        cfg["n_samples"] = 100
+    spec = importlib.util.spec_from_file_location(
+        f"bench_cfg_{name.replace('-', '_')}",
+        os.path.join(here, "configs", f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.lower(cfg, mod.make_weights(cfg, 2300001505, serve=True))
+
+
+@pytest.mark.parametrize("name", ["cepc-pid-hybrid", "jsc-plf-lut",
+                                  "jsc-hlf-lut", "wide-cell"])
+def test_stage_forms(name, monkeypatch):
+    """Every "lut" stage of the three benchmark configurations takes the
+    contraction; a stage whose one-hot would be wider than the cap keeps
+    the gather, bit-exactly."""
+    from repro.kernels.lut_serve import _ONEHOT_MAX_RATIO, stage_form
+
+    prog = _wide_cell_prog() if name == "wide-cell" else \
+        _bench_config_prog(name, monkeypatch)
+    stages, why = compose_fused_stages(prog)
+    assert stages is not None, why
+    forms = tuple(stage_form(st) for st in stages.stages)
+    if name == "wide-cell":
+        (st,) = stages.stages
+        assert 4096 > _ONEHOT_MAX_RATIO * st.mask.size
+        assert forms == ("gather",)
+        engine = compile_program(prog)
+        assert engine.stage_forms == ("gather",)
+        verify_engine(engine, prog, n_random=64)
+    else:
+        assert forms == tuple("onehot" if st.kind == "lut" else st.kind
+                              for st in stages.stages)
+        assert "onehot" in forms
+
+
+@pytest.mark.parametrize("build_prog", [
+    lambda: _mixed_shift_prog(3, seed=5), _wide_cell_prog],
+    ids=["onehot", "gather"])
+def test_bundle_engine_keeps_stage_forms(build_prog, tmp_path):
+    """An engine rebuilt from a saved bundle (no range metadata) takes the
+    forms the fresh engine took and stays bit-exact."""
+    from repro.serve.api import EngineSpec, build
+    from repro.serve.artifact import load_artifact, save_artifact
+
+    prog = build_prog()
+    fresh = compile_program(prog)
+    path = str(tmp_path / "m.npz")
+    save_artifact(path, prog)
+    loaded = build(load_artifact(path), EngineSpec(verify="skip")).engine
+    assert loaded.stage_forms == fresh.stage_forms
+    lo, hi = input_code_bounds(prog)
+    codes = _each_code_rows(lo, hi, np.random.default_rng(1))
+    np.testing.assert_array_equal(
+        np.asarray(jax.device_get(loaded.run(codes)), np.int64),
+        prog.run(codes))
 
 
 def test_engine_run_float_matches_interpreter():

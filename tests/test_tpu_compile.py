@@ -142,3 +142,25 @@ def test_serve_engines_partition_over_four_devices(engine, mesh4):
     assert len(compiled.output_shardings.device_set) == 4
     if engine == "pallas":
         assert "tpu_custom_call" in text
+
+
+def test_onehot_row_blocks_partition_over_four_devices(mesh4, monkeypatch):
+    """The PID hybrid's "lut" stages in the one-hot form, forced into the
+    row-block loop: each device loops over its own rows, with no collective,
+    and no one-hot is left in the device's temporaries."""
+    from repro.kernels import lut_serve
+
+    monkeypatch.setattr(lut_serve, "_ONEHOT_BLOCK_BYTES", 1 << 22)
+    engine = lut_serve.compile_program(_pid_prog(), engine="fused",
+                                       mesh=mesh4)
+    assert engine.stage_forms == ("mac", "onehot", "onehot", "onehot", "sum")
+    x = jax.ShapeDtypeStruct((4 * B_SERVE, engine.n_inputs), jnp.int32,
+                             sharding=NamedSharding(mesh4, P("data", None)))
+    compiled = engine._runner.lower(x).compile()
+    text = compiled.as_text()
+    assert " while(" in text and "stage1_lut_onehot" in text
+    assert not any(op in text for op in ("all-gather", "all-reduce",
+                                         "all-to-all", "collective-permute"))
+    assert len(compiled.output_shardings.device_set) == 4
+    # a 1024-row shard's one-hot of stage 1 alone would take 85 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 25
